@@ -132,7 +132,7 @@ def kernel_suite(smoke: bool = False, out_path=None) -> dict:
     bcount = plan.num_canvases
     slots = jnp.asarray(slots_np)
     records = jnp.asarray(plan.records)
-    impl = "pallas_interpret"
+    impl = stitch_ops.pallas_impl()
 
     cfg_fp, params_fp, serve_fp, rules_fp = build_detector(canvas=m)
     cfg_q, params_q, serve_q, rules_q = build_detector(canvas=m,

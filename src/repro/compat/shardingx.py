@@ -1,50 +1,24 @@
-"""Version-gated sharding compatibility layer.
-
-jax's mesh-construction API has drifted across the versions this repo
-supports:
-
-* **new jax** (>= 0.6-era): ``jax.make_mesh(shape, axes, axis_types=...)``
-  with ``jax.sharding.AxisType`` explicit-sharding annotations, plus the
-  ``jax.sharding.set_mesh`` context and ``get_abstract_mesh`` ambient-mesh
-  query.
-* **mid jax** (0.4.35 .. pre-AxisType, e.g. the 0.4.37 in the dev image):
-  ``jax.make_mesh(shape, axes)`` exists but takes no ``axis_types``;
-  ``AxisType``/``set_mesh``/``get_abstract_mesh`` are absent.
-* **old jax** (0.4.30 .. 0.4.34): no ``jax.make_mesh`` at all — meshes are
-  built from ``jax.experimental.mesh_utils.create_device_mesh`` + ``Mesh``.
+"""The one place the tree calls JAX's mesh and ambient-mesh API.
 
 Everything in the tree that constructs a mesh or needs the ambient-mesh
 machinery routes through this module; ``jax.sharding.AxisType`` must never
 be referenced anywhere else (enforced by ``tests/test_compat_sharding.py``).
-All meshes are Auto-typed: on new jax we pass ``AxisType.Auto`` explicitly,
-which matches the implicit behaviour of the older constructors, so compiled
-programs are identical on both sides of the gate.
+All meshes are Auto-typed (``AxisType.Auto`` on every axis), so the
+logical sharding rules in :mod:`repro.sharding` decide placement.  Each
+function is a thin call to the installed JAX; keeping them here keeps the
+call sites unchanged if that API moves again.
 """
 from __future__ import annotations
 
-import inspect
 from typing import Optional, Sequence, Tuple
 
 import jax
-from jax.sharding import Mesh
-
-# ---------------------------------------------------------------- feature
-# detection (import-time, once) -------------------------------------------
-HAS_AXIS_TYPE: bool = hasattr(jax.sharding, "AxisType")
-HAS_MAKE_MESH: bool = hasattr(jax, "make_mesh")
-MAKE_MESH_HAS_AXIS_TYPES: bool = (
-    HAS_MAKE_MESH
-    and "axis_types" in inspect.signature(jax.make_mesh).parameters)
-HAS_SET_MESH: bool = hasattr(jax.sharding, "set_mesh")
-HAS_ABSTRACT_MESH: bool = hasattr(jax.sharding, "get_abstract_mesh")
+from jax.sharding import AxisType, Mesh
 
 
-def auto_axis_types(n_axes: int) -> Optional[Tuple]:
-    """``(AxisType.Auto,) * n`` on new jax, ``None`` where the concept
-    does not exist (callers must then omit the kwarg entirely)."""
-    if not HAS_AXIS_TYPE:
-        return None
-    return (jax.sharding.AxisType.Auto,) * n_axes
+def auto_axis_types(n_axes: int) -> Tuple:
+    """``(AxisType.Auto,) * n_axes``."""
+    return (AxisType.Auto,) * n_axes
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str], *,
@@ -54,62 +28,31 @@ def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str], *,
     ``devices`` restricts construction to an explicit device list
     (defaults to all of ``jax.devices()``).
     """
-    if MAKE_MESH_HAS_AXIS_TYPES:
-        return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
-                             devices=devices,
-                             axis_types=auto_axis_types(len(axis_names)))
-    if HAS_MAKE_MESH:
-        return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
-                             devices=devices)
-    # pre-0.4.35: build the device ndarray by hand
-    from jax.experimental import mesh_utils
-    devs = mesh_utils.create_device_mesh(tuple(axis_shapes), devices=devices)
-    return Mesh(devs, tuple(axis_names))
+    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
+                         devices=devices,
+                         axis_types=auto_axis_types(len(axis_names)))
 
 
 def mesh_from_devices(device_array, axis_names: Sequence[str]) -> Mesh:
     """Mesh from an explicit device ndarray (the elastic re-mesh path,
     where surviving rows of a failed mesh are re-assembled in place)."""
-    types = auto_axis_types(len(tuple(axis_names)))
-    if types is not None:
-        return Mesh(device_array, tuple(axis_names), axis_types=types)
-    return Mesh(device_array, tuple(axis_names))
+    names = tuple(axis_names)
+    return Mesh(device_array, names, axis_types=auto_axis_types(len(names)))
 
 
 def use_mesh(mesh: Mesh):
-    """Context manager with ``jax.sharding.set_mesh`` semantics.
-
-    On old jax, falls back to the legacy global-mesh context
-    (``Mesh.__enter__``), which is what ``set_mesh`` replaced; both make
-    ``mesh`` ambient for jit lowering and sharding constraints.
-    """
-    if HAS_SET_MESH:
-        return jax.sharding.set_mesh(mesh)
-    return mesh
+    """Context manager making ``mesh`` ambient (``jax.sharding.set_mesh``)
+    for jit lowering and sharding constraints."""
+    return jax.sharding.set_mesh(mesh)
 
 
 def get_abstract_mesh() -> Optional[object]:
-    """The ambient mesh, or None when there is none.
-
-    New jax: ``jax.sharding.get_abstract_mesh()`` (set by ``set_mesh``).
-    Old jax: the legacy global physical mesh that ``use_mesh``'s
-    ``with mesh:`` fallback installs — without this branch every
-    logical sharding constraint would silently no-op on old jax and the
-    two sides of the gate would compile different programs.
-    Query axis sizes via ``mesh_axis_sizes`` (the two mesh types spell
-    them differently).
-    """
-    if HAS_ABSTRACT_MESH:
-        try:
-            mesh = jax.sharding.get_abstract_mesh()
-        except Exception:
-            return None
-    else:
-        try:
-            from jax.interpreters import pxla
-            mesh = pxla.thread_resources.env.physical_mesh
-        except Exception:
-            return None
+    """The ambient mesh, or None when there is none.  Query axis sizes via
+    ``mesh_axis_sizes``."""
+    try:
+        mesh = jax.sharding.get_abstract_mesh()
+    except Exception:
+        return None
     if mesh is None or getattr(mesh, "empty", True):
         return None
     return mesh
@@ -117,16 +60,9 @@ def get_abstract_mesh() -> Optional[object]:
 
 def mesh_axis_sizes(mesh) -> dict:
     """{axis name: size} for abstract and physical meshes alike."""
-    try:
-        return dict(zip(mesh.axis_names, mesh.axis_sizes))
-    except AttributeError:
-        return dict(mesh.shape)
+    return dict(zip(mesh.axis_names, mesh.axis_sizes))
 
 
 def cost_analysis_dict(compiled) -> dict:
-    """Normalize ``Compiled.cost_analysis()`` across the same version gate:
-    old jax returns a one-element list of dicts, new jax the dict itself."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca or {}
+    """``Compiled.cost_analysis()`` as a dict (empty when XLA gives none)."""
+    return compiled.cost_analysis() or {}

@@ -512,6 +512,12 @@ class DeviceExecutor:
     ``jax.block_until_ready``); tests and benchmarks substitute a hook
     that also joins non-JAX future-likes.
 
+    ``obj_threshold`` is the objectness a grid cell needs to be routed
+    as a detection.  ``use_pallas`` runs the unfused stitch/unstitch as
+    Pallas kernels, and ``fuse`` the fused stitch->embed and
+    decode->gather kernels; ``stitch_ops.pallas_impl`` compiles them on
+    TPU and interprets them on CPU.
+
     Multi-model serving: ``models`` maps a registry model name to a
     :class:`ModelRuntime` — or to a zero-arg callable returning one,
     resolved and cached on first use so unused models are never built.
@@ -529,7 +535,8 @@ class DeviceExecutor:
                  models: Optional[Dict[str, object]] = None,
                  tokens_fn: Optional[Callable] = None,
                  embed_kernel=None, embed_bias=None,
-                 patch: Optional[int] = None):
+                 patch: Optional[int] = None,
+                 obj_threshold: float = 0.5):
         self.serve_fn = serve_fn
         self.params = params
         self.m, self.n = canvas_m, canvas_n
@@ -544,6 +551,7 @@ class DeviceExecutor:
         self.embed_kernel = embed_kernel
         self.embed_bias = embed_bias
         self.patch = patch
+        self.obj_threshold = obj_threshold
         self._runtimes: Dict[Optional[str], ModelRuntime] = {}
         self.store = FrameStore()
         self.n_invocations = 0
@@ -626,7 +634,6 @@ class DeviceExecutor:
                 crops.append(frame[patch.y0:patch.y1, patch.x0:patch.x1])
         slots = stitch_ops.pack_plan_host(crops, plan)
         records = jnp.asarray(plan.records)
-        impl = "pallas_interpret" if self.use_pallas else "xla"
         if self.fuse and rt.tokens_fn is not None \
                 and rt.embed_kernel is not None and rt.patch is not None:
             # fused hot path: stitch->patch-embed emits the token batch
@@ -634,7 +641,9 @@ class DeviceExecutor:
             # tokens, and decode+gather lands straight in per-patch slot
             # grids — no host round-trip through canvas-space outputs.
             # The canvas batch never exists, so mesh sharding (which
-            # pads canvases, not records) does not apply here.
+            # pads canvases, not records) does not apply here.  The
+            # fused path exists only as Pallas kernels.
+            impl = stitch_ops.pallas_impl()
             tokens = stitch_ops.stitch_embed(
                 jnp.asarray(slots), records, rt.embed_kernel,
                 rt.embed_bias, rt.canvas_m, rt.canvas_n, rt.patch,
@@ -645,6 +654,7 @@ class DeviceExecutor:
             self.n_invocations += 1
             self.n_fused += 1
             return {"plan": plan, "fused": fused, "slots": slots, "t0": t0}
+        impl = stitch_ops.pallas_impl() if self.use_pallas else "xla"
         canvases = stitch_ops.stitch_canvases(
             jnp.asarray(slots), records, rt.canvas_m, rt.canvas_n, impl=impl)
         sharded = False
@@ -678,7 +688,8 @@ class DeviceExecutor:
         if "fused" in payload:
             sync(payload["fused"])
             per_frame = stitch_ops.route_fused(
-                plan, inv.patches, np.asarray(payload["fused"]))
+                plan, inv.patches, np.asarray(payload["fused"]),
+                obj_threshold=self.obj_threshold)
             # the unfused evidence (gathered slots) equals the input
             # crops by construction, so the fused path serves it from
             # the packed slots it already holds on the host
@@ -687,7 +698,8 @@ class DeviceExecutor:
             sync((payload["obj"], payload["patch_out"]))
             per_frame = stitch_ops.route_detections(
                 plan, inv.patches, np.asarray(payload["obj"]),
-                np.asarray(payload["boxes"]))
+                np.asarray(payload["boxes"]),
+                obj_threshold=self.obj_threshold)
             evidence = np.asarray(payload["patch_out"])
         per_frame_pixels: Dict[object, List[np.ndarray]] = {}
         for i, patch in enumerate(inv.patches):
@@ -801,7 +813,7 @@ def make_executor(name: str, **cfg):
 
     cls = lookup("executor", _EXECUTORS, name)
     device_only = {"fuse", "tokens_fn", "embed_kernel", "embed_bias",
-                   "patch"}
+                   "patch", "obj_threshold"}
     if cls is SimExecutor:
         drop = {"max_inflight", "models"} | device_only
     elif cls is AsyncDeviceExecutor:

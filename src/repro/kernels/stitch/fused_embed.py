@@ -1,33 +1,41 @@
-"""Fused Pallas TPU kernels for the device hot path (ROADMAP item 3).
+"""Fused Pallas TPU kernels for the device hot path.
 
 Two fusions kill the HBM round-trips that bracket the detector:
 
 ``stitch_embed_pallas`` — stitch -> patchify -> patch-embed in one launch.
-Each canvas is assembled in a VMEM scratch buffer while the patch-slot
-stream is double-buffered HBM->VMEM with ``pltpu.make_async_copy`` (two
-DMA buffers + two semaphores; slot k+1 is in flight while slot k is
-composited).  The assembled canvas never leaves VMEM: it is patchified in
-row chunks and multiplied by the patch-embed projection in place, so the
-kernel emits the (B, seq, d_model) token batch directly and the
-(B, M, N, C) canvas batch never materializes in HBM.
+Grid (B canvases, K placement slots): the patch-slot stream is pipelined
+HBM->VMEM by the slot BlockSpec (one slot per step, selected by the
+record's slot id), and each canvas is assembled in a VMEM scratch buffer
+with the same aligned-window rotate+blend as ``stitch_pallas``.  At the
+canvas's last placement step the resident canvas is patchified and
+multiplied by the patch-embed projection in place, so the kernel emits
+the (B, seq, d) token batch directly and the (B, M, N, C) canvas batch
+never materializes in HBM.
+
+The resident canvas is stored as 128-lane column blocks,
+``(C, N/128, M, 128)``: the TPU's strided row loads need a 128-wide base.
+The embed contracts one (channel, row-in-patch) pair at a time: a strided
+load picks that pixel row of every token row, ``(rows, N)``; a transpose
+and a tile-aligned reshape regroup it to ``(side_n * rows, patch)`` —
+one token per row, its ``patch`` columns on the lanes — and a matmul with
+that pair's ``(patch, d)`` slice of the projection accumulates the tokens.
+No in-kernel reshape ever splits the lane axis.
 
 ``unstitch_decode_pallas`` — head decode + placement gather in one launch.
-The detector's raw (B, side, side, 5) head outputs are decoded in-kernel
-(sigmoid objectness, cell-relative centers, exp box sizes — the same math
-as ``detector.decode_boxes``) and each placement's hits are scattered
+The detector's raw head outputs are decoded in-kernel (sigmoid objectness,
+cell-relative centers, exp box sizes — the same math as
+``detector.decode_boxes``) and each placement's hits are scattered
 straight to its patch slot.  A decoded center always lies inside its own
 grid cell (both offsets are sigmoids), so masking on center-in-placement
 over the full grid is exact and the canvas-space (obj, boxes) tensors are
-never materialized or round-tripped through the host.
+never materialized or round-tripped through the host.  Head channels are
+moved to the front outside the kernel, so every in-kernel array is a
+(side_m, side_n) plane and every mask has the rank of the data it selects.
 
 Boxes are stored clipped to the placement rectangle and translated to
 placement-local pixels; ``ops.route_fused`` only adds each patch's frame
 origin.  Invalid records park on the dummy slot past the real patches,
 exactly like ``unstitch_pallas``.
-
-The K placement steps are unrolled in Python (K is the plan's pow2-
-bucketed slots-per-canvas, small and static), which keeps the "prefetch
-slot k+1" control flow out of traced conditionals.
 """
 from __future__ import annotations
 
@@ -35,80 +43,79 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-#: default patch-rows per embed matmul chunk (overridable per-call; the
-#: hillclimb cell "kernel_blocks" searches this)
-DEFAULT_BLOCK_ROWS = 4
+from repro.kernels.stitch.stitch import (LANES, placement_mask, rotate,
+                                         slots_channel_major, sublane_tile,
+                                         vmem_limit, window_origin,
+                                         window_shape)
+
+#: default token rows per embed matmul chunk (overridable per call)
+DEFAULT_BLOCK_ROWS = 8
 
 
 def _stitch_embed_kernel(records_ref,        # SMEM (B, K, 6) int32
-                         slots_hbm,          # ANY  (P, Hmax, Wmax, C)
-                         wk_ref,             # VMEM (patch*patch*C, d)
+                         patch_ref,          # VMEM (1, C, hw, ww)
+                         w_ref,              # VMEM (C, patch, patch, d)
                          bias_ref,           # VMEM (1, d)
                          out_ref,            # VMEM (1, seq, d)
-                         *, m: int, n: int, patch: int, k_steps: int,
-                         hmax: int, wmax: int, c: int, block_rows: int,
-                         slot_dtype):
+                         canvas_ref,         # VMEM (C, N/L, M, L) f32
+                         *, m: int, n: int, lane: int, hw: int, ww: int,
+                         patch: int, block_rows: int):
     b = pl.program_id(0)
+    k = pl.program_id(1)
+    c = canvas_ref.shape[0]
 
-    def scoped(canvas, scratch, sem):
-        def copy(k, buf):
-            return pltpu.make_async_copy(
-                slots_hbm.at[pl.ds(records_ref[b, k, 1], 1)],
-                scratch.at[buf], sem.at[buf])
+    @pl.when(k == 0)
+    def _init():
+        canvas_ref[...] = jnp.zeros_like(canvas_ref)
 
-        canvas[...] = jnp.zeros_like(canvas)
-        copy(0, 0).start()
-        for k in range(k_steps):
-            buf = k % 2
-            if k + 1 < k_steps:
-                copy(k + 1, (k + 1) % 2).start()
-            copy(k, buf).wait()
+    valid = records_ref[b, k, 0]
+    ys, dy = window_origin(records_ref[b, k, 3], m, hw,
+                           sublane_tile(jnp.float32))
+    xs, dx = window_origin(records_ref[b, k, 2], n, ww, LANES)
+    w = records_ref[b, k, 4]
+    h = records_ref[b, k, 5]
 
-            valid = records_ref[b, k, 0]
-            slot_x = records_ref[b, k, 2]
-            slot_y = records_ref[b, k, 3]
-            w = records_ref[b, k, 4]
-            h = records_ref[b, k, 5]
-            img = scratch[buf, 0]                     # (Hmax, Wmax, C)
-            # clamp+roll placement, same as _stitch_kernel; the store is
-            # unconditional with validity folded into the mask so the
-            # unrolled loop carries no traced control flow
-            ys = jnp.clip(slot_y, 0, m - hmax)
-            xs = jnp.clip(slot_x, 0, n - wmax)
-            dy = slot_y - ys
-            dx = slot_x - xs
-            rows = jax.lax.broadcasted_iota(jnp.int32, (hmax, wmax), 0)
-            cols = jax.lax.broadcasted_iota(jnp.int32, (hmax, wmax), 1)
-            mask = ((rows >= dy) & (rows < dy + h)
-                    & (cols >= dx) & (cols < dx + w) & (valid > 0))
-            shifted = jnp.roll(jnp.roll(img, dy, axis=0), dx, axis=1)
-            window = canvas[pl.ds(ys, hmax), pl.ds(xs, wmax), :]
-            canvas[pl.ds(ys, hmax), pl.ds(xs, wmax), :] = (
-                jnp.where(mask[..., None], shifted, window))
+    @pl.when(valid > 0)
+    def _place():
+        mask = placement_mask((hw, ww), dy, dx, h, w)
+        q0 = xs // lane
+        for ci in range(c):
+            shifted = rotate(patch_ref[0, ci], dy, dx)
+            for qq in range(ww // lane):
+                cols = slice(qq * lane, (qq + 1) * lane)
+                window = canvas_ref[ci, q0 + qq, pl.ds(ys, hw), :]
+                canvas_ref[ci, q0 + qq, pl.ds(ys, hw), :] = jnp.where(
+                    mask[:, cols], shifted[:, cols], window)
 
-        # embed phase: patchify the resident canvas in row chunks and
-        # project each chunk on the MXU (same layout as vit.patchify)
+    @pl.when(k == pl.num_programs(1) - 1)
+    def _embed():
         side_m, side_n = m // patch, n // patch
-        for r0 in range(0, side_m, block_rows):
-            br = min(block_rows, side_m - r0)
-            px = canvas[pl.ds(r0 * patch, br * patch), :, :]
-            x = px.reshape(br, patch, side_n, patch, c)
-            x = x.transpose(0, 2, 1, 3, 4).reshape(br * side_n,
-                                                   patch * patch * c)
-            y = jnp.dot(x.astype(wk_ref.dtype), wk_ref[...],
-                        preferred_element_type=jnp.float32)
-            y = y + bias_ref[0].astype(jnp.float32)
-            out_ref[0, pl.ds(r0 * side_n, br * side_n), :] = (
+        d = out_ref.shape[-1]
+        for i0 in range(0, side_m, block_rows):
+            rows = min(block_rows, side_m - i0)
+            acc = jnp.zeros((side_n * rows, d), jnp.float32)
+            for ci in range(c):
+                def row_in_patch(r, acc, ci=ci, i0=i0, rows=rows):
+                    # pixel row r of token rows i0..i0+rows: (rows, N)
+                    a = jnp.concatenate(
+                        [canvas_ref[ci, q, pl.ds(i0 * patch + r, rows,
+                                                 stride=patch), :]
+                         for q in range(n // lane)], axis=1)
+                    # -> (side_n * rows, patch): token (j, i), column s
+                    x = a.T.reshape(side_n, patch, rows)
+                    x = jnp.swapaxes(x, 1, 2).reshape(side_n * rows, patch)
+                    return acc + jnp.dot(x.astype(w_ref.dtype), w_ref[ci, r],
+                                         preferred_element_type=jnp.float32)
+                acc = jax.lax.fori_loop(0, patch, row_in_patch, acc)
+            # token rows come out column-major (j, i): reorder to (i, j)
+            y = jnp.swapaxes(acc.reshape(side_n, rows, d), 0, 1)
+            y = y.reshape(rows * side_n, d) + bias_ref[...].astype(jnp.float32)
+            out_ref[0, pl.ds(i0 * side_n, rows * side_n), :] = (
                 y.astype(out_ref.dtype))
-
-    pl.run_scoped(
-        scoped,
-        canvas=pltpu.VMEM((m, n, c), slot_dtype),
-        scratch=pltpu.VMEM((2, 1, hmax, wmax, c), slot_dtype),
-        sem=pltpu.SemaphoreType.DMA((2,)))
 
 
 def stitch_embed_pallas(patch_pixels: jnp.ndarray, records: jnp.ndarray,
@@ -137,66 +144,82 @@ def stitch_embed_pallas(patch_pixels: jnp.ndarray, records: jnp.ndarray,
         return jnp.broadcast_to(bias.astype(kernel.dtype), (b, seq, d))
 
     block_rows = min(block_rows or DEFAULT_BLOCK_ROWS, side_m)
+    lane = LANES if n % LANES == 0 else n
+    # the canvas is assembled in float32 (the rotate is 32-bit), which
+    # holds every slot dtype exactly
+    hw, ww = window_shape(m, n, hmax, wmax, jnp.float32)
+    slots = slots_channel_major(patch_pixels, hw, ww)
+    # (patch*patch*C, d) rows are ordered (r, s, c) as in vit.patchify;
+    # the kernel takes one (c, r) slice of shape (patch, d) at a time
+    w4 = kernel.reshape(patch, patch, c, d).transpose(2, 0, 1, 3)
     body = functools.partial(
-        _stitch_embed_kernel, m=m, n=n, patch=patch, k_steps=k,
-        hmax=hmax, wmax=wmax, c=c, block_rows=block_rows,
-        slot_dtype=patch_pixels.dtype)
+        _stitch_embed_kernel, m=m, n=n, lane=lane, hw=hw, ww=ww,
+        patch=patch, block_rows=block_rows)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(b,),
+        grid=(b, k),
         in_specs=[
-            # the slot array stays in HBM; the kernel DMAs slots itself
-            pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
-            pl.BlockSpec((patch * patch * c, d), lambda bi, recs: (0, 0)),
-            pl.BlockSpec((1, d), lambda bi, recs: (0, 0)),
+            pl.BlockSpec((1, c, hw, ww),
+                         lambda bi, ki, recs: (recs[bi, ki, 1], 0, 0, 0)),
+            pl.BlockSpec((c, patch, patch, d),
+                         lambda bi, ki, recs: (0, 0, 0, 0)),
+            pl.BlockSpec((1, d), lambda bi, ki, recs: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, seq, d), lambda bi, recs: (bi, 0, 0)),
+        out_specs=pl.BlockSpec((1, seq, d), lambda bi, ki, recs: (bi, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((c, n // lane, m, lane), jnp.float32)],
     )
+    isz = np.dtype(patch_pixels.dtype).itemsize
+    wsz = np.dtype(kernel.dtype).itemsize
     return pl.pallas_call(
         body,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, seq, d), kernel.dtype),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit(
+            c * m * n * 4, 2 * c * hw * ww * isz, 2 * kernel.size * wsz,
+            2 * seq * d * wsz, 2 * side_n * block_rows * d * 4,
+            4 * hw * ww * 4)),
         interpret=interpret,
-    )(records, patch_pixels, kernel, bias.reshape(1, d))
+    )(records, slots, w4, bias.reshape(1, d))
 
 
 def _unstitch_decode_kernel(records_ref,     # SMEM (B, K, 6) int32
-                            raw_ref,         # VMEM (1, side_m, side_n, 5)
-                            out_ref,         # VMEM (1, side_m, side_n, 5)
+                            raw_ref,         # VMEM (1, 5, side_m, side_n)
+                            out_ref,         # VMEM (1, 5, side_m, side_n)
                             *, patch: int, side_m: int, side_n: int):
     b = pl.program_id(0)
     k = pl.program_id(1)
 
-    valid = records_ref[b, k, 0]
+    # an invalid record gets an empty rectangle, so nothing hits
+    valid = records_ref[b, k, 0] > 0
     x0 = records_ref[b, k, 2].astype(jnp.float32)
     y0 = records_ref[b, k, 3].astype(jnp.float32)
-    w = records_ref[b, k, 4].astype(jnp.float32)
-    h = records_ref[b, k, 5].astype(jnp.float32)
+    w = jnp.where(valid, records_ref[b, k, 4], 0).astype(jnp.float32)
+    h = jnp.where(valid, records_ref[b, k, 5], 0).astype(jnp.float32)
 
-    raw = raw_ref[0].astype(jnp.float32)              # (side_m, side_n, 5)
     cell = float(patch)
-    obj = jax.nn.sigmoid(raw[..., 0])
+    obj = jax.nn.sigmoid(raw_ref[0, 0].astype(jnp.float32))
     gy = jax.lax.broadcasted_iota(jnp.int32, (side_m, side_n), 0)
     gx = jax.lax.broadcasted_iota(jnp.int32, (side_m, side_n), 1)
-    cx = (gx.astype(jnp.float32) + jax.nn.sigmoid(raw[..., 1])) * cell
-    cy = (gy.astype(jnp.float32) + jax.nn.sigmoid(raw[..., 2])) * cell
-    bw = jnp.exp(jnp.clip(raw[..., 3], -6, 6)) * cell
-    bh = jnp.exp(jnp.clip(raw[..., 4], -6, 6)) * cell
+    cx = (gx.astype(jnp.float32)
+          + jax.nn.sigmoid(raw_ref[0, 1].astype(jnp.float32))) * cell
+    cy = (gy.astype(jnp.float32)
+          + jax.nn.sigmoid(raw_ref[0, 2].astype(jnp.float32))) * cell
+    bw = jnp.exp(jnp.clip(raw_ref[0, 3].astype(jnp.float32), -6, 6)) * cell
+    bh = jnp.exp(jnp.clip(raw_ref[0, 4].astype(jnp.float32), -6, 6)) * cell
 
     # center-in-placement assignment over the full grid (sigmoid offsets
     # keep every center inside its own cell, so no cell outside the
     # placement can hit), then clip to the rect and shift to
     # placement-local pixels — the same math route_detections applies
     # on the host to decode_boxes outputs
-    hit = ((valid > 0)
-           & (cx >= x0) & (cx < x0 + w)
-           & (cy >= y0) & (cy < y0 + h))
-    bx0 = jnp.clip(cx - bw / 2, x0, x0 + w) - x0
-    by0 = jnp.clip(cy - bh / 2, y0, y0 + h) - y0
-    bx1 = jnp.clip(cx + bw / 2, x0, x0 + w) - x0
-    by1 = jnp.clip(cy + bh / 2, y0, y0 + h) - y0
-    dec = jnp.stack([obj, bx0, by0, bx1, by1], axis=-1)
-    out_ref[0] = jnp.where(hit[..., None], dec, jnp.zeros_like(dec))
+    hit = (cx >= x0) & (cx < x0 + w) & (cy >= y0) & (cy < y0 + h)
+    dec = (obj,
+           jnp.clip(cx - bw / 2, x0, x0 + w) - x0,
+           jnp.clip(cy - bh / 2, y0, y0 + h) - y0,
+           jnp.clip(cx + bw / 2, x0, x0 + w) - x0,
+           jnp.clip(cy + bh / 2, y0, y0 + h) - y0)
+    for ch, val in enumerate(dec):
+        out_ref[0, ch] = jnp.where(hit, val, 0.0)
 
 
 def unstitch_decode_pallas(raw: jnp.ndarray, records: jnp.ndarray,
@@ -224,12 +247,12 @@ def unstitch_decode_pallas(raw: jnp.ndarray, records: jnp.ndarray,
         num_scalar_prefetch=1,
         grid=(b, k),
         in_specs=[
-            pl.BlockSpec((1, side_m, side_n, ch),
+            pl.BlockSpec((1, ch, side_m, side_n),
                          lambda bi, ki, recs: (bi, 0, 0, 0)),
         ],
         # invalid records park on the dummy slot, as in unstitch_pallas
         out_specs=pl.BlockSpec(
-            (1, side_m, side_n, ch),
+            (1, ch, side_m, side_n),
             lambda bi, ki, recs: (jnp.where(recs[bi, ki, 0] > 0,
                                             recs[bi, ki, 1], num_patches),
                                   0, 0, 0)),
@@ -237,8 +260,8 @@ def unstitch_decode_pallas(raw: jnp.ndarray, records: jnp.ndarray,
     out = pl.pallas_call(
         body,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((num_patches + 1, side_m, side_n, ch),
+        out_shape=jax.ShapeDtypeStruct((num_patches + 1, ch, side_m, side_n),
                                        jnp.float32),
         interpret=interpret,
-    )(records, raw)
-    return out[:num_patches]
+    )(records, raw.transpose(0, 3, 1, 2))
+    return out[:num_patches].transpose(0, 2, 3, 1)

@@ -24,6 +24,18 @@ from repro.kernels.stitch.ref import (stitch_embed_reference,
 from repro.kernels.stitch.stitch import stitch_pallas, unstitch_pallas
 
 
+def pallas_impl(platform: str | None = None) -> str:
+    """The ``impl`` that runs the Pallas kernels on ``platform`` (default:
+    ``jax.default_backend()``): compiled on TPU, interpreted on CPU.  Any
+    other backend is an error, never a silent fallback."""
+    platform = platform or jax.default_backend()
+    if platform == "tpu":
+        return "pallas"
+    if platform == "cpu":
+        return "pallas_interpret"
+    raise ValueError(f"no Pallas stitch kernels for platform {platform!r}")
+
+
 @functools.partial(jax.jit, static_argnames=("m", "n", "impl"))
 def stitch_canvases(patch_pixels, records, m: int, n: int,
                     impl: str = "xla"):

@@ -14,38 +14,47 @@ import jax
 import jax.numpy as jnp
 
 
+def _record(records, i, k):
+    """Fields (valid, slot, x, y, w, h) of flattened record ``i``, and its
+    canvas index."""
+    bi = i // k
+    return bi, tuple(records[bi, i % k, j] for j in range(6))
+
+
 def stitch_reference(patch_pixels: jnp.ndarray, records: jnp.ndarray,
                      m: int, n: int) -> jnp.ndarray:
     p_, hmax, wmax, c = patch_pixels.shape
     b, k, _ = records.shape
     out = jnp.zeros((b, m, n, c), patch_pixels.dtype)
+    if b * k * p_ == 0:
+        return out
 
-    rows = jnp.arange(hmax)
-    cols = jnp.arange(wmax)
+    rows = jnp.arange(hmax)[:, None]
+    cols = jnp.arange(wmax)[None, :]
 
-    for bi in range(b):
-        for ki in range(k):
-            valid, slot, x, y, w, h = (records[bi, ki, i] for i in range(6))
-            img = jax.lax.dynamic_index_in_dim(patch_pixels, slot, axis=0,
-                                               keepdims=False)
-            # clamp the Hmax x Wmax window inside the canvas; shift the
-            # valid-region mask by the clamp offset
-            ys = jnp.clip(y, 0, m - hmax)
-            xs = jnp.clip(x, 0, n - wmax)
-            dy = y - ys
-            dx = x - xs
-            mask = ((rows[:, None] >= dy) & (rows[:, None] < dy + h)
-                    & (cols[None, :] >= dx) & (cols[None, :] < dx + w)
-                    & (valid > 0))
-            window = jax.lax.dynamic_slice(out[bi], (ys, xs, 0),
-                                           (hmax, wmax, c))
-            # the patch's (h, w) region starts at its slot origin (0, 0);
-            # shift it to (dy, dx) inside the window
-            shifted = jnp.roll(jnp.roll(img, dy, axis=0), dx, axis=1)
-            blended = jnp.where(mask[..., None], shifted, window)
-            out = out.at[bi].set(
-                jax.lax.dynamic_update_slice(out[bi], blended, (ys, xs, 0)))
-    return out
+    def place(i, out):
+        bi, (valid, slot, x, y, w, h) = _record(records, i, k)
+        img = jax.lax.dynamic_index_in_dim(patch_pixels, slot, axis=0,
+                                           keepdims=False)
+        # clamp the Hmax x Wmax window inside the canvas; shift the
+        # valid-region mask by the clamp offset
+        ys = jnp.clip(y, 0, m - hmax)
+        xs = jnp.clip(x, 0, n - wmax)
+        dy = y - ys
+        dx = x - xs
+        mask = ((rows >= dy) & (rows < dy + h)
+                & (cols >= dx) & (cols < dx + w) & (valid > 0))
+        window = jax.lax.dynamic_slice(out, (bi, ys, xs, 0),
+                                       (1, hmax, wmax, c))[0]
+        # the patch's (h, w) region starts at its slot origin (0, 0);
+        # shift it to (dy, dx) inside the window
+        shifted = jnp.roll(jnp.roll(img, dy, axis=0), dx, axis=1)
+        blended = jnp.where(mask[..., None], shifted, window)
+        return jax.lax.dynamic_update_slice(out, blended[None],
+                                            (bi, ys, xs, 0))
+
+    # a loop, not B*K unrolled copies: compile time stays flat in K
+    return jax.lax.fori_loop(0, b * k, place, out)
 
 
 def unstitch_reference(canvases: jnp.ndarray, records: jnp.ndarray,
@@ -56,30 +65,28 @@ def unstitch_reference(canvases: jnp.ndarray, records: jnp.ndarray,
     b, m, n, c = canvases.shape
     _, k, _ = records.shape
     out = jnp.zeros((num_patches, hmax, wmax, c), canvases.dtype)
-    if num_patches == 0:
+    if num_patches == 0 or b * k == 0:
         return out
 
-    rows = jnp.arange(hmax)
-    cols = jnp.arange(wmax)
+    rows = jnp.arange(hmax)[:, None]
+    cols = jnp.arange(wmax)[None, :]
 
-    for bi in range(b):
-        for ki in range(k):
-            valid, slot, x, y, w, h = (records[bi, ki, i] for i in range(6))
-            ys = jnp.clip(y, 0, m - hmax)
-            xs = jnp.clip(x, 0, n - wmax)
-            window = jax.lax.dynamic_slice(canvases[bi], (ys, xs, 0),
-                                           (hmax, wmax, c))
-            shifted = jnp.roll(jnp.roll(window, -(y - ys), axis=0),
-                               -(x - xs), axis=1)
-            mask = ((rows[:, None] < h) & (cols[None, :] < w) & (valid > 0))
-            patch = jnp.where(mask[..., None], shifted,
-                              jnp.zeros_like(shifted))
-            prev = jax.lax.dynamic_index_in_dim(out, slot, axis=0,
-                                                keepdims=False)
-            upd = jnp.where(valid > 0, patch, prev)
-            out = jax.lax.dynamic_update_slice(
-                out, upd[None], (slot, 0, 0, 0))
-    return out
+    def gather(i, out):
+        bi, (valid, slot, x, y, w, h) = _record(records, i, k)
+        ys = jnp.clip(y, 0, m - hmax)
+        xs = jnp.clip(x, 0, n - wmax)
+        window = jax.lax.dynamic_slice(canvases, (bi, ys, xs, 0),
+                                       (1, hmax, wmax, c))[0]
+        shifted = jnp.roll(jnp.roll(window, -(y - ys), axis=0),
+                           -(x - xs), axis=1)
+        mask = (rows < h) & (cols < w) & (valid > 0)
+        patch = jnp.where(mask[..., None], shifted, jnp.zeros_like(shifted))
+        prev = jax.lax.dynamic_index_in_dim(out, slot, axis=0,
+                                            keepdims=False)
+        upd = jnp.where(valid > 0, patch, prev)
+        return jax.lax.dynamic_update_slice(out, upd[None], (slot, 0, 0, 0))
+
+    return jax.lax.fori_loop(0, b * k, gather, out)
 
 
 def stitch_embed_reference(patch_pixels: jnp.ndarray, records: jnp.ndarray,
@@ -112,37 +119,38 @@ def unstitch_decode_reference(raw: jnp.ndarray, records: jnp.ndarray,
     b, side_m, side_n, _ = raw.shape
     _, k, _ = records.shape
     out = jnp.zeros((num_patches, side_m, side_n, 5), jnp.float32)
-    if num_patches == 0:
+    if num_patches == 0 or b * k == 0:
         return out
 
     cell = float(patch)
     gy, gx = jnp.meshgrid(jnp.arange(side_m), jnp.arange(side_n),
                           indexing="ij")
-    for bi in range(b):
-        r = raw[bi].astype(jnp.float32)
+
+    def gather(i, out):
+        bi, (valid, slot, x, y, w, h) = _record(records, i, k)
+        r = jax.lax.dynamic_index_in_dim(raw, bi, axis=0,
+                                         keepdims=False).astype(jnp.float32)
         obj = jax.nn.sigmoid(r[..., 0])
         cx = (gx + jax.nn.sigmoid(r[..., 1])) * cell
         cy = (gy + jax.nn.sigmoid(r[..., 2])) * cell
         bw = jnp.exp(jnp.clip(r[..., 3], -6, 6)) * cell
         bh = jnp.exp(jnp.clip(r[..., 4], -6, 6)) * cell
-        for ki in range(k):
-            valid, slot, x, y, w, h = (records[bi, ki, i] for i in range(6))
-            x0, y0 = x.astype(jnp.float32), y.astype(jnp.float32)
-            wf, hf = w.astype(jnp.float32), h.astype(jnp.float32)
-            hit = ((valid > 0)
-                   & (cx >= x0) & (cx < x0 + wf)
-                   & (cy >= y0) & (cy < y0 + hf))
-            dec = jnp.stack([
-                obj,
-                jnp.clip(cx - bw / 2, x0, x0 + wf) - x0,
-                jnp.clip(cy - bh / 2, y0, y0 + hf) - y0,
-                jnp.clip(cx + bw / 2, x0, x0 + wf) - x0,
-                jnp.clip(cy + bh / 2, y0, y0 + hf) - y0,
-            ], axis=-1)
-            val = jnp.where(hit[..., None], dec, jnp.zeros_like(dec))
-            prev = jax.lax.dynamic_index_in_dim(out, slot, axis=0,
-                                                keepdims=False)
-            upd = jnp.where(valid > 0, val, prev)
-            out = jax.lax.dynamic_update_slice(
-                out, upd[None], (slot, 0, 0, 0))
-    return out
+        x0, y0 = x.astype(jnp.float32), y.astype(jnp.float32)
+        wf, hf = w.astype(jnp.float32), h.astype(jnp.float32)
+        hit = ((valid > 0)
+               & (cx >= x0) & (cx < x0 + wf)
+               & (cy >= y0) & (cy < y0 + hf))
+        dec = jnp.stack([
+            obj,
+            jnp.clip(cx - bw / 2, x0, x0 + wf) - x0,
+            jnp.clip(cy - bh / 2, y0, y0 + hf) - y0,
+            jnp.clip(cx + bw / 2, x0, x0 + wf) - x0,
+            jnp.clip(cy + bh / 2, y0, y0 + hf) - y0,
+        ], axis=-1)
+        val = jnp.where(hit[..., None], dec, jnp.zeros_like(dec))
+        prev = jax.lax.dynamic_index_in_dim(out, slot, axis=0,
+                                            keepdims=False)
+        upd = jnp.where(valid > 0, val, prev)
+        return jax.lax.dynamic_update_slice(out, upd[None], (slot, 0, 0, 0))
+
+    return jax.lax.fori_loop(0, b * k, gather, out)

@@ -7,22 +7,42 @@ function instance DMAs compact patch slots HBM->VMEM and assembles a whole
 kernel gathers each placement's pixels back out of the canvases so
 per-patch detector outputs can be routed to their source frames.
 
-Grid: (B canvases, K placement slots) — the leading grid dimension batches
-over canvases, so one launch stitches an entire multi-canvas packing plan.
-The canvas BlockSpec maps every k step of a canvas to the same (M, N, C)
-block, so the canvas stays resident in VMEM across its K placement steps
-(accumulation pattern); the patch input streams one (Hmax, Wmax, C) slot
+Layout.  Inside the kernels every image is channel-major, ``(C, rows,
+cols)``, so the pixel columns sit on the 128-lane axis; the public
+wrappers take and return the detector's NHWC arrays and transpose at the
+boundary.  (An NHWC block would put C=3 on the lanes and pad every pixel
+to 128 lanes: a 1024² f32 canvas would need 512 MiB of VMEM.)
+
+Placement.  A record places a (h, w) patch at pixel (y, x) — offsets the
+TPU cannot address in VMEM, which only takes dynamic row offsets that are
+multiples of the sublane tile and column offsets that are multiples of
+128.  So each placement is blended through an *aligned window*: its
+origin is (y, x) rounded down to those multiples (clamped inside the
+canvas) and its static size is the slot grown by one alignment step
+(:func:`window_shape`), so the placement always fits inside it at the
+residual offset (dy, dx).  The slot is padded to the window size outside
+the kernel, rotated by (dy, dx) with ``pltpu.roll`` (a dynamic rotate,
+32-bit only — pixels are rotated as float32, which is exact for the 8- and
+16-bit payloads too) and blended under the placement mask.  Unstitch
+rotates the window back by (-dy, -dx) and masks to (h, w).
+
+Grid: (B canvases, C channels, K placement slots).  The canvas block of a
+(b, c) pair stays resident in VMEM across its K placement steps
+(accumulation pattern); the slot block streams one (window) slot channel
 per step, selected by the record's slot id via scalar prefetch.  Records
-ride in SMEM and drive the dynamic in-VMEM loads/stores (``pl.ds`` — never
-raw integer indices, which the state-discharge pass rejects).
+ride in SMEM.  Unstitch inverts the mapping: the canvas block is the
+resident input and the slot is the output block, scattered to
+``records[b, k, 1]``.  Invalid records are parked on a dummy slot appended
+past the real patches so they can never clobber live output; the dummy is
+sliced off on return.
 
-Unstitch inverts the mapping: the canvas block is the streamed input and
-the patch slot is the output block, scattered to ``records[b, k, 1]``.
-Invalid records are parked on a dummy slot appended past the real patches
-so they can never clobber live output; the dummy is sliced off on return.
-
-VMEM budget (defaults): canvas 1024x1024x3 bf16 = 6.0 MiB + one patch slot
-512x512x3 bf16 = 1.5 MiB << 16 MiB/core.
+VMEM budget at the serving shape (canvas 1024², 512² slots, f32): the
+canvas block is 4 MiB and the slot window 520x640 = 1.3 MiB, both
+double-buffered, plus a few window-sized temporaries.  The TPU compiler
+accepts stitch with 12 MiB and unstitch with 13 MiB of scoped VMEM, and
+the fused stitch->embed with 27 MiB (smallest limits that compile for a
+v5e); the last is above the 16 MiB default, so every call states its own
+limit (:func:`vmem_limit`), computed from its buffers with headroom.
 """
 from __future__ import annotations
 
@@ -30,44 +50,97 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+LANES = 128
+
+
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+def sublane_tile(dtype) -> int:
+    """Rows per VMEM tile for ``dtype``: 8 for 32-bit, 16 for 16-bit,
+    32 for 8-bit (the alignment of dynamic row offsets)."""
+    return 8 * max(1, 4 // np.dtype(dtype).itemsize)
+
+
+def window_shape(m: int, n: int, hmax: int, wmax: int, dtype):
+    """Static (rows, cols) of the aligned window a placement is blended
+    through: the slot grown by one alignment step, capped at the canvas.
+    A canvas axis that is not a whole number of tiles gets a window the
+    size of the axis (origin always 0)."""
+    sub = sublane_tile(dtype)
+    hw = m if m % sub else min(_round_up(hmax + sub - 1, sub), m)
+    ww = n if n % LANES else min(_round_up(wmax + LANES - 1, LANES), n)
+    return hw, ww
+
+
+def window_origin(pos, extent: int, win: int, align: int):
+    """Aligned window origin for a placement starting at ``pos`` along an
+    axis of ``extent``; returns (origin, pos - origin).  The residual is
+    reduced mod ``win`` so garbage positions of invalid records still give
+    an in-range rotate."""
+    if win == extent:
+        return 0, jax.lax.rem(pos, win)
+    start = jnp.minimum((pos // align) * align, extent - win)
+    return pl.multiple_of(start, align), jax.lax.rem(pos - start, win)
+
+
+def placement_mask(shape, dy, dx, h, w):
+    """Boolean (rows, cols) mask of the rectangle [dy, dy+h) x [dx, dx+w)."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    return (rows >= dy) & (rows < dy + h) & (cols >= dx) & (cols < dx + w)
+
+
+def rotate(img, dy, dx):
+    """Rotate a 2-D image by (dy, dx) (``jnp.roll`` semantics) as float32:
+    the TPU's dynamic rotate handles 32-bit data only."""
+    img = pltpu.roll(img.astype(jnp.float32), dy, 0)
+    return pltpu.roll(img, dx, 1)
+
+
+def slots_channel_major(patch_pixels, hw: int, ww: int):
+    """(P, Hmax, Wmax, C) slots -> (P, C, hw, ww), zero-padded to the
+    window size."""
+    _, hmax, wmax, _ = patch_pixels.shape
+    x = patch_pixels.transpose(0, 3, 1, 2)
+    return jnp.pad(x, ((0, 0), (0, 0), (0, hw - hmax), (0, ww - wmax)))
+
+
+def vmem_limit(*buffer_bytes: int) -> int:
+    """Scoped-VMEM limit for a call: the listed buffers plus half again
+    for Mosaic's own temporaries and 4 MiB of headroom."""
+    return int(sum(buffer_bytes) * 1.5) + (4 << 20)
+
 
 def _stitch_kernel(records_ref,          # SMEM (B, K, 6) int32
-                   patch_ref,            # VMEM (1, Hmax, Wmax, C)
-                   out_ref,              # VMEM (1, M, N, C)
-                   *, m: int, n: int, hmax: int, wmax: int):
+                   patch_ref,            # VMEM (1, 1, hw, ww)
+                   out_ref,              # VMEM (1, 1, M, N)
+                   *, m: int, n: int, hw: int, ww: int, sub: int):
     b = pl.program_id(0)
-    k = pl.program_id(1)
+    k = pl.program_id(2)
 
     @pl.when(k == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
     valid = records_ref[b, k, 0]
-    slot_x = records_ref[b, k, 2]
-    slot_y = records_ref[b, k, 3]
+    ys, dy = window_origin(records_ref[b, k, 3], m, hw, sub)
+    xs, dx = window_origin(records_ref[b, k, 2], n, ww, LANES)
     w = records_ref[b, k, 4]
     h = records_ref[b, k, 5]
 
     @pl.when(valid > 0)
     def _place():
-        img = patch_ref[0]                            # (Hmax, Wmax, C)
-        # clamp the Hmax x Wmax window inside the canvas; shift the patch
-        # by the clamp offset so its (h, w) region still lands at (y, x)
-        ys = jnp.clip(slot_y, 0, m - hmax)
-        xs = jnp.clip(slot_x, 0, n - wmax)
-        dy = slot_y - ys
-        dx = slot_x - xs
-        rows = jax.lax.broadcasted_iota(jnp.int32, (hmax, wmax), 0)
-        cols = jax.lax.broadcasted_iota(jnp.int32, (hmax, wmax), 1)
-        mask = ((rows >= dy) & (rows < dy + h)
-                & (cols >= dx) & (cols < dx + w))
-        shifted = jnp.roll(jnp.roll(img, dy, axis=0), dx, axis=1)
-        window = out_ref[0, pl.ds(ys, hmax), pl.ds(xs, wmax), :]
-        out_ref[0, pl.ds(ys, hmax), pl.ds(xs, wmax), :] = (
-            jnp.where(mask[..., None], shifted, window))
+        shifted = rotate(patch_ref[0, 0], dy, dx).astype(out_ref.dtype)
+        mask = placement_mask((hw, ww), dy, dx, h, w)
+        window = out_ref[0, 0, pl.ds(ys, hw), pl.ds(xs, ww)]
+        out_ref[0, 0, pl.ds(ys, hw), pl.ds(xs, ww)] = (
+            jnp.where(mask, shifted, window))
 
 
 def stitch_pallas(patch_pixels: jnp.ndarray, records: jnp.ndarray,
@@ -77,56 +150,58 @@ def stitch_pallas(patch_pixels: jnp.ndarray, records: jnp.ndarray,
     p_, hmax, wmax, c = patch_pixels.shape
     b, k, _ = records.shape
     assert hmax <= m and wmax <= n, "patch slot larger than canvas"
+    dtype = patch_pixels.dtype
     if b == 0 or k == 0 or p_ == 0:
         # empty packing: a zero canvas batch, no degenerate kernel launch
-        return jnp.zeros((b, m, n, c), patch_pixels.dtype)
+        return jnp.zeros((b, m, n, c), dtype)
 
-    kernel = functools.partial(_stitch_kernel, m=m, n=n, hmax=hmax, wmax=wmax)
+    hw, ww = window_shape(m, n, hmax, wmax, dtype)
+    slots = slots_channel_major(patch_pixels, hw, ww)
+    kernel = functools.partial(_stitch_kernel, m=m, n=n, hw=hw, ww=ww,
+                               sub=sublane_tile(dtype))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(b, k),
+        grid=(b, c, k),
         in_specs=[
-            # one patch slot per (b, k) step, selected by the record's slot id
-            pl.BlockSpec((1, hmax, wmax, c),
-                         lambda bi, ki, recs: (recs[bi, ki, 1], 0, 0, 0)),
+            # one slot channel per step, selected by the record's slot id
+            pl.BlockSpec((1, 1, hw, ww),
+                         lambda bi, ci, ki, recs: (recs[bi, ki, 1], ci, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, m, n, c),
-                               lambda bi, ki, recs: (bi, 0, 0, 0)),
+        out_specs=pl.BlockSpec((1, 1, m, n),
+                               lambda bi, ci, ki, recs: (bi, ci, 0, 0)),
     )
-    return pl.pallas_call(
+    isz = np.dtype(dtype).itemsize
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, m, n, c), patch_pixels.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, c, m, n), dtype),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit(
+            2 * m * n * isz, 2 * hw * ww * isz, 4 * hw * ww * 4)),
         interpret=interpret,
-    )(records, patch_pixels)
+    )(records, slots)
+    return out.transpose(0, 2, 3, 1)
 
 
 def _unstitch_kernel(records_ref,        # SMEM (B, K, 6) int32
-                     canvas_ref,         # VMEM (1, M, N, C)
-                     out_ref,            # VMEM (1, Hmax, Wmax, C)
-                     *, m: int, n: int, hmax: int, wmax: int):
+                     canvas_ref,         # VMEM (1, 1, M, N)
+                     out_ref,            # VMEM (1, 1, hw, ww)
+                     *, m: int, n: int, hw: int, ww: int, sub: int):
     b = pl.program_id(0)
-    k = pl.program_id(1)
+    k = pl.program_id(2)
 
     valid = records_ref[b, k, 0]
-    slot_x = records_ref[b, k, 2]
-    slot_y = records_ref[b, k, 3]
+    ys, dy = window_origin(records_ref[b, k, 3], m, hw, sub)
+    xs, dx = window_origin(records_ref[b, k, 2], n, ww, LANES)
     w = records_ref[b, k, 4]
-    h = records_ref[b, k, 5]
+    h = jnp.where(valid > 0, records_ref[b, k, 5], 0)
 
-    ys = jnp.clip(slot_y, 0, m - hmax)
-    xs = jnp.clip(slot_x, 0, n - wmax)
-    dy = slot_y - ys
-    dx = slot_x - xs
-    window = canvas_ref[0, pl.ds(ys, hmax), pl.ds(xs, wmax), :]
-    # the placement starts at (dy, dx) inside the clamped window; shift it
-    # back to the slot origin and zero everything outside the (h, w) region
-    shifted = jnp.roll(jnp.roll(window, -dy, axis=0), -dx, axis=1)
-    rows = jax.lax.broadcasted_iota(jnp.int32, (hmax, wmax), 0)
-    cols = jax.lax.broadcasted_iota(jnp.int32, (hmax, wmax), 1)
-    mask = (rows < h) & (cols < w) & (valid > 0)
-    out_ref[0] = jnp.where(mask[..., None], shifted,
-                           jnp.zeros_like(shifted))
+    window = canvas_ref[0, 0, pl.ds(ys, hw), pl.ds(xs, ww)]
+    # the placement starts at (dy, dx) inside the aligned window; rotate
+    # it back to the slot origin and zero everything outside (h, w)
+    shifted = rotate(window, jax.lax.rem(hw - dy, hw),
+                     jax.lax.rem(ww - dx, ww))
+    mask = placement_mask((hw, ww), 0, 0, h, w)
+    out_ref[0, 0] = jnp.where(mask, shifted, 0.0).astype(out_ref.dtype)
 
 
 def unstitch_pallas(canvases: jnp.ndarray, records: jnp.ndarray,
@@ -144,31 +219,35 @@ def unstitch_pallas(canvases: jnp.ndarray, records: jnp.ndarray,
     b, m, n, c = canvases.shape
     _, k, _ = records.shape
     assert hmax <= m and wmax <= n, "patch slot larger than canvas"
+    dtype = canvases.dtype
     if num_patches == 0 or b == 0 or k == 0:
-        return jnp.zeros((num_patches, hmax, wmax, c), canvases.dtype)
+        return jnp.zeros((num_patches, hmax, wmax, c), dtype)
 
-    kernel = functools.partial(_unstitch_kernel, m=m, n=n,
-                               hmax=hmax, wmax=wmax)
+    hw, ww = window_shape(m, n, hmax, wmax, dtype)
+    kernel = functools.partial(_unstitch_kernel, m=m, n=n, hw=hw, ww=ww,
+                               sub=sublane_tile(dtype))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(b, k),
+        grid=(b, c, k),
         in_specs=[
-            pl.BlockSpec((1, m, n, c),
-                         lambda bi, ki, recs: (bi, 0, 0, 0)),
+            pl.BlockSpec((1, 1, m, n),
+                         lambda bi, ci, ki, recs: (bi, ci, 0, 0)),
         ],
         # scatter each placement to its slot; invalid records park on the
         # dummy slot at index num_patches so they cannot clobber live data
         out_specs=pl.BlockSpec(
-            (1, hmax, wmax, c),
-            lambda bi, ki, recs: (jnp.where(recs[bi, ki, 0] > 0,
-                                            recs[bi, ki, 1], num_patches),
-                                  0, 0, 0)),
+            (1, 1, hw, ww),
+            lambda bi, ci, ki, recs: (jnp.where(recs[bi, ki, 0] > 0,
+                                                recs[bi, ki, 1], num_patches),
+                                      ci, 0, 0)),
     )
+    isz = np.dtype(dtype).itemsize
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((num_patches + 1, hmax, wmax, c),
-                                       canvases.dtype),
+        out_shape=jax.ShapeDtypeStruct((num_patches + 1, c, hw, ww), dtype),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit(
+            2 * m * n * isz, 2 * hw * ww * isz, 4 * hw * ww * 4)),
         interpret=interpret,
-    )(records, canvases)
-    return out[:num_patches]
+    )(records, canvases.transpose(0, 3, 1, 2))
+    return out[:num_patches, :, :hmax, :wmax].transpose(0, 2, 3, 1)

@@ -2,8 +2,8 @@
 
 FUNCTIONS, not module-level constants: importing this module never touches
 jax device state, so smoke tests keep their 1-device world.  All factories
-route through the version-gated compat layer (``repro.compat.shardingx``),
-which papers over the ``jax.make_mesh`` / axis-types API drift.
+route through the compat layer (``repro.compat.shardingx``), the one place
+the tree calls JAX's mesh API.
 """
 from __future__ import annotations
 

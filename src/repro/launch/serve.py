@@ -54,8 +54,21 @@ data-parallel layout — the stitched canvas batch is padded to the mesh's
 the canvases.  On a 1-device world the mesh degenerates to 1x1 and every
 step is identical to the unsharded path.
 
+``--model NAME --full-width`` serves a registry model at its published
+widths and native canvas (``ModelSpec.build(reduced=False)``), e.g. the
+paper's ``tangram`` ViT-B/32 detector on 1024² canvases; without it a
+registry model builds its reduced, CPU-sized trunk.  ``--devices N``
+restricts the serve mesh to the first N local devices.
+
+:func:`main` prints its summary and also returns it as a dict (patches,
+invocations, routed detections and the detections themselves, SLO
+violations, frames still held), so callers can compare runs in one
+process.
+
 Usage:
   PYTHONPATH=src python -m repro.launch.serve --frames 40 --slo 1.0
+  PYTHONPATH=src python -m repro.launch.serve --model tangram --full-width \
+    --use-pallas-stitch
   PYTHONPATH=src python -m repro.launch.serve --async-device --max-inflight 4
   PYTHONPATH=src python -m repro.launch.serve --workers 2 --online-latency
   PYTHONPATH=src python -m repro.launch.serve --source synthetic \
@@ -90,6 +103,7 @@ from repro.core.fleet import (FleetInvokerPool, FleetPlan, FleetCostModel,
 from repro.core.workers import (WorkerPoolExecutor, device_worker_pool,
                                 make_placement, share_frame_store,
                                 weight_caches)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_serve_mesh, make_worker_meshes
 from repro.models import detector as detector_lib
 from repro.sharding import ShardingConfig
@@ -144,13 +158,29 @@ def build_source(args, frame_sink, slos):
     return make_source("trace", arrivals=events)
 
 
+def _record_routed(executor, routed: dict):
+    """Copy every completion's routed detections into ``routed``
+    ({frame_id: [(score, box_xyxy), ...]}) ahead of the executor's own
+    delivery, which drops them."""
+    deliver = executor.on_complete
+
+    def on_complete(comp):
+        for fid, dets in comp.outputs[0].items():
+            routed.setdefault(fid, []).extend(dets)
+        deliver(comp)
+
+    executor.on_complete = on_complete
+
+
 def main(argv=None):
     p = argparse.ArgumentParser()
     p.add_argument("--frames", type=int, default=40)
     p.add_argument("--slo", default="1.0",
                    help="SLO seconds; a comma list (e.g. 0.5,2.0) runs one "
                         "camera per class and shards the invoker per SLO")
-    p.add_argument("--canvas", type=int, default=256)
+    p.add_argument("--canvas", type=int, default=None,
+                   help="canvas side in pixels (default 256, or the "
+                        "model's native canvas with --full-width)")
     p.add_argument("--scene", type=int, default=0)
     p.add_argument("--fps", type=float, default=10.0)
     p.add_argument("--bandwidth-mbps", type=float, default=40.0,
@@ -174,8 +204,11 @@ def main(argv=None):
                         "ingestion window: drop frames, degrade RoI "
                         "quality (drops at 2x), or ignore")
     p.add_argument("--use-pallas-stitch", action="store_true",
-                   help="assemble canvases with the Pallas kernel "
-                        "(interpret mode on CPU)")
+                   help="assemble canvases with the Pallas kernels "
+                        "(compiled on TPU, interpreted on CPU)")
+    p.add_argument("--obj-threshold", type=float, default=0.5,
+                   help="objectness a detector cell needs to be routed "
+                        "back to its frame as a detection")
     p.add_argument("--fuse", action="store_true",
                    help="fused device hot path: stitch->patch-embed and "
                         "decode->gather run as single kernels, so the "
@@ -234,6 +267,13 @@ def main(argv=None):
     p.add_argument("--model", default=None,
                    help="registry model to serve (repro.core.models; "
                         "default: the historical tiny built-in detector)")
+    p.add_argument("--full-width", action="store_true",
+                   help="build the registry models at their published "
+                        "widths and native canvas instead of the reduced "
+                        "CPU-sized trunk; needs --model")
+    p.add_argument("--devices", type=int, default=None,
+                   help="span the serve mesh over the first N local "
+                        "devices (default: all)")
     p.add_argument("--model-map", action="append", default=None,
                    metavar="CLASS=MODEL",
                    help="route an SLO class to a registry model, e.g. "
@@ -259,6 +299,11 @@ def main(argv=None):
         p.error("--cameras must be >= 1")
     if args.source == "file" and not args.frames_path:
         p.error("--source file requires --frames-path")
+    if args.full_width and not args.model:
+        p.error("--full-width builds a registry model: give --model")
+    n_local = len(jax.devices())
+    if args.devices is not None and not 1 <= args.devices <= n_local:
+        p.error(f"--devices must be in [1, {n_local}]")
     try:
         slos = [float(s) for s in str(args.slo).split(",")]
     except ValueError:
@@ -288,7 +333,7 @@ def main(argv=None):
         model=args.model, model_map=model_map,
         shards=args.shards, planner=args.planner, parallel=args.parallel)
 
-    m = n = args.canvas
+    enable_compile_cache()
     if config.quantize and config.multi_model:
         # --quantize reroutes every referenced registry model to its
         # _int8 variant (when one is registered): quantized weights,
@@ -305,18 +350,34 @@ def main(argv=None):
             model=_q(config.model),
             model_map=({k: _q(v) for k, v in config.model_map.items()}
                        if config.model_map else None))
+    specs = ({name: make_model(name) for name in config.model_names()}
+             if config.multi_model else {})
+    if args.full_width:
+        sides = {side for s in specs.values()
+                 for side in (s.canvas_m, s.canvas_n)}
+        if len(sides) != 1:
+            p.error(f"--full-width needs models that share one square "
+                    f"native canvas, got sides {sorted(sides)}")
+        native = sides.pop()
+        if args.canvas not in (None, native):
+            p.error(f"--full-width serves at the native canvas {native}, "
+                    f"not --canvas {args.canvas}")
+        args.canvas = native
+    elif args.canvas is None:
+        args.canvas = 256
+    m = n = args.canvas
     if config.multi_model:
-        # lazy registry builds: each referenced model jit-compiles its
-        # (reduced) trunk at the CLI canvas, with per-name weight seeds
-        specs = {name: make_model(name) for name in config.model_names()}
-        builds = {name: spec.build(canvas=args.canvas)
+        # registry builds: each referenced model jit-compiles its trunk
+        # (reduced unless --full-width) at the canvas, with per-name
+        # weight seeds
+        builds = {name: spec.build(canvas=m, reduced=not args.full_width)
                   for name, spec in specs.items()}
         default_model = config.model or sorted(builds)[0]
         cfg, params, serve_fn, rules = builds[default_model]
         print(f"models: {', '.join(sorted(builds))} "
               f"(default {default_model})")
     else:
-        specs, builds, default_model = {}, {}, None
+        builds, default_model = {}, None
         cfg, params, serve_fn, rules = build_detector(
             args.canvas, quantize=config.quantize)
 
@@ -332,9 +393,10 @@ def main(argv=None):
                     patch=mcfg.patch)
     n_slices = config.shards or config.n_workers
     if n_slices > 1:
-        meshes = make_worker_meshes(n_slices)
+        meshes = make_worker_meshes(n_slices,
+                                    devices=jax.devices()[:args.devices])
     else:
-        meshes = [make_serve_mesh()]
+        meshes = [make_serve_mesh(args.devices)]
     mesh = meshes[0]
     axis_sizes = shardingx.mesh_axis_sizes(mesh)
     print(f"serve mesh: {len(meshes)} worker(s) x "
@@ -401,6 +463,7 @@ def main(argv=None):
                 canvas_m=m, canvas_n=n, use_pallas=config.use_pallas,
                 fuse=config.fuse, mesh=meshes[i % len(meshes)],
                 rules=rules, max_inflight=config.max_inflight,
+                obj_threshold=args.obj_threshold,
                 models=runtimes(meshes[i % len(meshes)]) if builds else None,
                 **fused_kwargs(cfg, params, rules))
             for i in range(config.shards)]
@@ -416,6 +479,7 @@ def main(argv=None):
                 canvas_m=m, canvas_n=n, use_pallas=config.use_pallas,
                 fuse=config.fuse, mesh=meshes[i], rules=rules,
                 max_inflight=config.max_inflight,
+                obj_threshold=args.obj_threshold,
                 models=runtimes(meshes[i]) if builds else None,
                 **fused_kwargs(cfg, params, rules)),
             placement=make_placement(config.placement),
@@ -426,6 +490,7 @@ def main(argv=None):
             canvas_m=m, canvas_n=n, use_pallas=config.use_pallas,
             fuse=config.fuse, mesh=mesh, rules=rules,
             max_inflight=config.max_inflight,
+            obj_threshold=args.obj_threshold,
             models=runtimes(mesh) if builds else None,
             **fused_kwargs(cfg, params, rules))
         if config.online_latency or caches is not None:
@@ -436,6 +501,9 @@ def main(argv=None):
             executor = WorkerPoolExecutor([executor], estimator=estimator,
                                           weight_caches=caches)
 
+    routed: dict = {}
+    for ex in shard_executors or [executor]:
+        _record_routed(ex, routed)
     source = build_source(args, frame_sink=executor.add_frame, slos=slos)
 
     def build_pool(fleet: bool = False):
@@ -565,6 +633,14 @@ def main(argv=None):
                          f"{cs['weight_hits'] + cs['weight_misses']}")
             print(f"  model {name}: {served} patches, "
                   f"{viol} violations{extra}")
+    return {"patches": stats.patches_emitted,
+            "invocations": _total("n_invocations"),
+            "fused": _total("n_fused"),
+            "sharded": _total("n_sharded"),
+            "detections": _total("n_detections"),
+            "routed": routed,
+            "violations": violated,
+            "frames_held": len(executor.frames)}
 
 
 if __name__ == "__main__":

@@ -48,8 +48,8 @@ def pytest_runtest_call(item):
 
 # Modules excluded from the CI fast lane.  The former tracked-red modules
 # (arch smoke, sharding API, multi-device dry-run, elastic re-mesh) went
-# green with the version-gated sharding compat layer
-# (src/repro/compat/shardingx.py) and now run in the enforced lane; only
+# green with the sharding compat layer (src/repro/compat/shardingx.py)
+# and now run in the enforced lane; only
 # genuinely heavyweight sweeps belong here.
 SLOW_MODULES: set = set()
 

@@ -1,9 +1,5 @@
-"""Version-gated sharding compat layer (repro.compat.shardingx).
-
-These tests exercise both sides of the gate regardless of the installed
-jax: the native side runs as-is, the fallback sides are forced by
-monkeypatching the feature flags.
-"""
+"""The sharding compat layer (repro.compat.shardingx): thin calls to the
+installed JAX's mesh and ambient-mesh API."""
 import os
 
 import jax
@@ -18,20 +14,18 @@ SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 class TestFeatureDetection:
     def test_flags_are_consistent(self):
-        # axis_types on make_mesh implies make_mesh itself exists
-        assert not (shardingx.MAKE_MESH_HAS_AXIS_TYPES
-                    and not shardingx.HAS_MAKE_MESH)
-        # AxisType implies make_mesh grew the axis_types kwarg (they
-        # shipped together)
-        if shardingx.HAS_AXIS_TYPE and shardingx.HAS_MAKE_MESH:
-            assert shardingx.MAKE_MESH_HAS_AXIS_TYPES
+        # the installed JAX offers every API the layer calls
+        import inspect
+        assert hasattr(jax.sharding, "AxisType")
+        assert "axis_types" in inspect.signature(jax.make_mesh).parameters
+        assert hasattr(jax.sharding, "set_mesh")
+        assert hasattr(jax.sharding, "get_abstract_mesh")
 
     def test_auto_axis_types_matches_gate(self):
-        types = shardingx.auto_axis_types(3)
-        if shardingx.HAS_AXIS_TYPE:
-            assert len(types) == 3
-        else:
-            assert types is None
+        assert shardingx.auto_axis_types(3) == \
+            (jax.sharding.AxisType.Auto,) * 3
+        mesh = make_unit_mesh()
+        assert tuple(mesh.axis_types) == shardingx.auto_axis_types(2)
 
 
 class TestMakeMesh:
@@ -40,23 +34,6 @@ class TestMakeMesh:
         assert tuple(mesh.axis_names) == ("data", "model")
         assert mesh.devices.shape == (1, 1)
         assert mesh_chips(mesh) == 1
-
-    def test_mesh_utils_fallback_builds_identical_mesh(self, monkeypatch):
-        native = shardingx.make_mesh((1, 1), ("data", "model"))
-        monkeypatch.setattr(shardingx, "MAKE_MESH_HAS_AXIS_TYPES", False)
-        monkeypatch.setattr(shardingx, "HAS_MAKE_MESH", False)
-        fallback = shardingx.make_mesh((1, 1), ("data", "model"))
-        assert tuple(fallback.axis_names) == tuple(native.axis_names)
-        assert fallback.devices.shape == native.devices.shape
-        assert (fallback.devices == native.devices).all()
-
-    def test_no_axis_types_midversion_fallback(self, monkeypatch):
-        if not shardingx.HAS_MAKE_MESH:
-            import pytest
-            pytest.skip("this jax has no jax.make_mesh to gate off")
-        monkeypatch.setattr(shardingx, "MAKE_MESH_HAS_AXIS_TYPES", False)
-        mesh = shardingx.make_mesh((1, 1), ("data", "model"))
-        assert tuple(mesh.axis_names) == ("data", "model")
 
     def test_serve_mesh_covers_local_devices(self):
         mesh = make_serve_mesh()
@@ -85,9 +62,8 @@ class TestUseMesh:
         assert shardingx.get_abstract_mesh() is None  # outside any ctx
 
     def test_ambient_mesh_visible_inside_ctx(self):
-        """Both gate sides must report the ambient mesh inside use_mesh —
-        otherwise logical sharding constraints silently no-op on one side
-        and the two sides compile different programs."""
+        """The ambient mesh is visible inside use_mesh — otherwise logical
+        sharding constraints would silently no-op."""
         mesh = make_unit_mesh()
         with shardingx.use_mesh(mesh):
             ambient = shardingx.get_abstract_mesh()
@@ -113,11 +89,6 @@ class TestCostAnalysisDict:
         def cost_analysis(self):
             return self._ca
 
-    def test_old_jax_list_form(self):
-        assert shardingx.cost_analysis_dict(
-            self._Compiled([{"flops": 5.0}])) == {"flops": 5.0}
-        assert shardingx.cost_analysis_dict(self._Compiled([])) == {}
-
     def test_new_jax_dict_form(self):
         assert shardingx.cost_analysis_dict(
             self._Compiled({"flops": 5.0})) == {"flops": 5.0}
@@ -134,7 +105,7 @@ class TestCostAnalysisDict:
 def test_no_axis_type_references_outside_compat():
     """The whole point of the layer: ``jax.sharding.AxisType`` must only
     ever be touched inside repro/compat/ — everything else routes through
-    the factory and survives both sides of the version gate."""
+    the factory, so an API move is repaired in one file."""
     offenders = []
     for root, _, files in os.walk(SRC):
         for name in files:

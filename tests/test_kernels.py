@@ -151,6 +151,19 @@ def test_stitch_kernel_single_patch_packing():
     assert float(jnp.abs(out[0, :, 12:]).sum()) == 0.0
 
 
+@pytest.mark.parametrize("platform,impl", [("tpu", "pallas"),
+                                           ("cpu", "pallas_interpret")])
+def test_pallas_impl_follows_the_platform(platform, impl):
+    """Compiled kernels on TPU, the interpreter only on CPU."""
+    assert stitch_ops.pallas_impl(platform) == impl
+
+
+def test_pallas_impl_refuses_other_platforms():
+    assert stitch_ops.pallas_impl() == "pallas_interpret"   # CPU tests
+    with pytest.raises(ValueError):
+        stitch_ops.pallas_impl("gpu")
+
+
 def test_stitch_jit_wrapper_impls_agree():
     rng = np.random.default_rng(5)
     slots = jnp.asarray(rng.normal(size=(3, 16, 16, 3)), jnp.float32)

@@ -53,6 +53,41 @@ def test_serve_driver_with_real_model_and_pallas_stitch():
                 "--use-pallas-stitch"])
 
 
+def test_serve_driver_pallas_paths_route_the_xla_detections():
+    """serve.main returns its summary; the Pallas stitch and fused paths
+    route exactly the XLA path's detections on the same trace.  The long
+    SLO makes every invocation fire on the canvas budget, so the plans do
+    not depend on measured latency."""
+    from repro.launch import serve
+    argv = ["--frames", "16", "--canvas", "128", "--slo", "1000"]
+    xla = serve.main(argv)
+    assert xla["patches"] > 0 and xla["invocations"] > 0
+    assert xla["frames_held"] == 0 and xla["fused"] == 0
+    assert xla["detections"] == sum(len(v) for v in xla["routed"].values())
+    pallas = serve.main(argv + ["--use-pallas-stitch"])
+    assert pallas["routed"] == xla["routed"]
+    fused = serve.main(argv + ["--fuse"])
+    assert fused["fused"] == fused["invocations"] == xla["invocations"]
+    assert fused["frames_held"] == 0
+    assert set(fused["routed"]) == set(xla["routed"])
+    for fid, dets in xla["routed"].items():
+        assert len(fused["routed"][fid]) == len(dets)
+        for (fs, fb), (xs, xb) in zip(fused["routed"][fid], dets):
+            assert fs == pytest.approx(xs, abs=1e-4)
+            assert fb == pytest.approx(xb, abs=1e-3)
+
+
+@pytest.mark.parametrize("argv", [
+    ["--full-width"],                                      # needs --model
+    ["--model", "tangram", "--full-width", "--canvas", "256"],
+    ["--devices", "0"],
+])
+def test_serve_driver_rejects_bad_width_and_device_flags(argv):
+    from repro.launch import serve
+    with pytest.raises(SystemExit):
+        serve.main(argv)
+
+
 def test_serve_driver_async_wall_clock_smoke():
     """launch/serve.py --async-device on a compressed wall clock: the
     full driver path through AsyncDeviceExecutor + WallClock."""
