@@ -27,11 +27,12 @@ One-chip phases:
    untrained trunk's objectness sits near 0.3 on these frames, so the
    runs route at ``THRESHOLD`` instead of the default 0.5, which would
    route nothing and leave nothing to compare.  The Pallas-stitch run
-   must route exactly the XLA run's detections; the fused run computes
-   the patch embedding in a different summation order, so its detections
-   must match those of the XLA run that clear the threshold by
-   ``MARGIN``, within ``SCORE_TOL`` and ``BOX_TOL``.  Every run must end
-   with no frame held.
+   must route exactly the XLA run's detections.  The fused run computes
+   the patch embedding in another summation order, so its bf16 tokens
+   differ by an ulp here and there: each of its detections and each of
+   the XLA run's must have a partner in the other run within
+   ``SCORE_TOL`` and ``BOX_TOL``, except one within ``MARGIN`` of the
+   threshold, which may flip.  Every run must end with no frame held.
 
 ``--four-chip`` runs one phase instead: the same serve data-parallel over
 all four chips (``make_serve_mesh()``), on a one-device mesh
@@ -57,11 +58,11 @@ sys.path.insert(0, str(Path(__file__).resolve().parent / "src"))
 CANVAS = 1024
 #: objectness a cell needs to be routed in the serve runs
 THRESHOLD = 0.25
-#: routed detections compared across runs that differ in summation order
-#: must clear the threshold by this much (the fused run's scores differ
-#: from the XLA run's by at most 0.0023 on a TPU v5e) ...
+#: across runs that differ in summation order, a detection may lack a
+#: partner only within this much of the threshold (the fused run's scores
+#: differed from the XLA run's by at most 0.0046 on a TPU v5e) ...
 MARGIN = 0.01
-#: ... and then agree in score and in box corners (pixels) within these
+#: ... and partners agree in score and in box corners (pixels) within these
 SCORE_TOL = 0.02
 BOX_TOL = 4.0
 
@@ -165,32 +166,37 @@ def serve(label: str, frames: int, *extra: str) -> dict:
 
 
 def match_routed(label: str, ref: dict, got: dict):
-    """Every detection that clears the threshold by ``MARGIN`` in one run
-    has a partner in the other run's frame within the tolerances."""
+    """Every detection of either run has a partner in the other run's
+    frame within ``BOX_TOL`` and ``SCORE_TOL``; only a detection within
+    ``MARGIN`` of the threshold may lack one, since the other run's score
+    for that cell may fall on the other side of it."""
     worst_s = worst_b = 0.0
-    compared = skipped = 0
+    paired = flipped = 0
     for a_name, a, b in (("ref", ref, got), ("got", got, ref)):
-        for fid in set(a) | set(b):
-            pool = list(b.get(fid, []))
-            for score, box in a.get(fid, []):
-                if abs(score - THRESHOLD) < MARGIN:
-                    skipped += 1
-                    continue
-                compared += 1
-                dists = [(max(abs(x - y) for x, y in zip(box, bb)),
-                          abs(score - s)) for s, bb in pool]
-                best = min(dists, default=None)
-                if (best is None or best[0] > BOX_TOL
-                        or best[1] > SCORE_TOL):
+        for fid, dets in a.items():
+            pool = b.get(fid, [])
+            for score, box in dets:
+                # nearest partner, box and score scaled by their tolerances
+                best = min(((max(abs(x - y) for x, y in zip(box, bb)),
+                             abs(score - s)) for s, bb in pool),
+                           key=lambda d: max(d[0] / BOX_TOL,
+                                             d[1] / SCORE_TOL),
+                           default=None)
+                if (best is not None and best[0] <= BOX_TOL
+                        and best[1] <= SCORE_TOL):
+                    paired += 1
+                    worst_b = max(worst_b, best[0])
+                    worst_s = max(worst_s, best[1])
+                elif abs(score - THRESHOLD) < MARGIN:
+                    flipped += 1
+                else:
                     raise RuntimeError(
                         f"{label}: {a_name} detection {score:.4f} "
                         f"{box} of frame {fid} has no partner "
                         f"(nearest: {best})")
-                worst_b = max(worst_b, best[0])
-                worst_s = max(worst_s, best[1])
-    log(f"  {label}: {compared} detections clear of the threshold matched "
-        f"({skipped} within {MARGIN} of it not compared); max score diff "
-        f"{worst_s!r}, max box diff {worst_b!r} px")
+    log(f"  {label}: {paired} detections paired, {flipped} within {MARGIN} "
+        f"of the threshold unpaired; max score diff {worst_s!r}, max box "
+        f"diff {worst_b!r} px")
 
 
 def serve_phase(frames: int):
