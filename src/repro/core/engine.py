@@ -95,6 +95,7 @@ from repro.core.framestore import FrameStore
 from repro.core.invoker import Invocation, SLOAwareInvoker
 from repro.core.partitioning import Patch
 from repro.core.stitching import validate
+from repro.core.telemetry import Telemetry
 from repro.data.video import Arrival
 from repro.serverless.platform import Platform
 
@@ -518,6 +519,13 @@ class DeviceExecutor:
     decode->gather kernels; ``stitch_ops.pallas_impl`` compiles them on
     TPU and interprets them on CPU.
 
+    ``telemetry`` (a :class:`~repro.core.telemetry.Telemetry`, off by
+    default) records the launch and finalize spans and their parts.  The
+    transfer counters are always on: ``bytes_to_device`` (slots and
+    records), ``bytes_from_device`` (every array fetched),
+    ``slot_pixels`` (slot capacity x H x W) and ``live_pixels`` (the
+    patches' own h x w).
+
     Multi-model serving: ``models`` maps a registry model name to a
     :class:`ModelRuntime` — or to a zero-arg callable returning one,
     resolved and cached on first use so unused models are never built.
@@ -536,7 +544,8 @@ class DeviceExecutor:
                  tokens_fn: Optional[Callable] = None,
                  embed_kernel=None, embed_bias=None,
                  patch: Optional[int] = None,
-                 obj_threshold: float = 0.5):
+                 obj_threshold: float = 0.5,
+                 telemetry: Optional[Telemetry] = None):
         self.serve_fn = serve_fn
         self.params = params
         self.m, self.n = canvas_m, canvas_n
@@ -552,6 +561,7 @@ class DeviceExecutor:
         self.embed_bias = embed_bias
         self.patch = patch
         self.obj_threshold = obj_threshold
+        self.telemetry = telemetry if telemetry is not None else Telemetry()
         self._runtimes: Dict[Optional[str], ModelRuntime] = {}
         self.store = FrameStore()
         self.n_invocations = 0
@@ -559,6 +569,10 @@ class DeviceExecutor:
         self.n_detections = 0
         self.n_sharded = 0
         self.evidence_bytes = 0
+        self.bytes_to_device = 0
+        self.bytes_from_device = 0
+        self.slot_pixels = 0
+        self.live_pixels = 0
 
     def _runtime(self, model: Optional[str]) -> ModelRuntime:
         """Resolve an invocation's model tag to its runtime (default
@@ -621,19 +635,47 @@ class DeviceExecutor:
 
         from repro.kernels.stitch import ops as stitch_ops
 
+        tel = self.telemetry
         t0 = self.clock()
-        rt = self._runtime(inv.model)
-        plan = inv.batch_plan()
-        crops = []
-        store = self.store
-        for patch in inv.patches:
-            frame = store.get(patch.frame_id)
-            if frame is None:
-                crops.append(np.zeros((patch.h, patch.w, 3), np.float32))
-            else:
-                crops.append(frame[patch.y0:patch.y1, patch.x0:patch.x1])
-        slots = stitch_ops.pack_plan_host(crops, plan)
-        records = jnp.asarray(plan.records)
+        with tel.span("tangram.executor.launch") as launch:
+            rt = self._runtime(inv.model)
+            plan = inv.batch_plan()
+            with tel.span("tangram.executor.gather"):
+                crops = []
+                store = self.store
+                for patch in inv.patches:
+                    frame = store.get(patch.frame_id)
+                    if frame is None:
+                        crops.append(np.zeros((patch.h, patch.w, 3),
+                                              np.float32))
+                    else:
+                        crops.append(
+                            frame[patch.y0:patch.y1, patch.x0:patch.x1])
+            with tel.span("tangram.executor.pack"):
+                slots = stitch_ops.pack_plan_host(crops, plan)
+            with tel.span("tangram.executor.put"):
+                slots_d = jnp.asarray(slots)
+                records = jnp.asarray(plan.records)
+            sent = slots_d.nbytes + records.nbytes
+            slot_px = slots.shape[0] * slots.shape[1] * slots.shape[2]
+            live_px = sum(p.h * p.w for p in inv.patches)
+            self.bytes_to_device += sent
+            self.slot_pixels += slot_px
+            self.live_pixels += live_px
+            launch.set(bytes_to_device=sent, slot_pixels=slot_px,
+                       live_pixels=live_px)
+            with tel.span("tangram.executor.enqueue"):
+                out = self._enqueue(rt, plan, slots_d, records)
+        out.update(plan=plan, t0=t0, inv=launch.inv)
+        if "fused" in out:
+            out["slots"] = slots
+        return out
+
+    def _enqueue(self, rt: ModelRuntime, plan, slots, records) -> dict:
+        """The jit calls of one invocation, on device-resident slots and
+        records: the device values ``_finalize`` joins."""
+        from repro.kernels.stitch import ops as stitch_ops
+
         if self.fuse and rt.tokens_fn is not None \
                 and rt.embed_kernel is not None and rt.patch is not None:
             # fused hot path: stitch->patch-embed emits the token batch
@@ -645,18 +687,17 @@ class DeviceExecutor:
             # fused path exists only as Pallas kernels.
             impl = stitch_ops.pallas_impl()
             tokens = stitch_ops.stitch_embed(
-                jnp.asarray(slots), records, rt.embed_kernel,
-                rt.embed_bias, rt.canvas_m, rt.canvas_n, rt.patch,
-                impl=impl)
+                slots, records, rt.embed_kernel, rt.embed_bias,
+                rt.canvas_m, rt.canvas_n, rt.patch, impl=impl)
             raw = rt.tokens_fn(rt.params, tokens)
             fused = stitch_ops.unstitch_decode(
                 raw, records, rt.patch, plan.slot_capacity, impl=impl)
             self.n_invocations += 1
             self.n_fused += 1
-            return {"plan": plan, "fused": fused, "slots": slots, "t0": t0}
+            return {"fused": fused}
         impl = stitch_ops.pallas_impl() if self.use_pallas else "xla"
         canvases = stitch_ops.stitch_canvases(
-            jnp.asarray(slots), records, rt.canvas_m, rt.canvas_n, impl=impl)
+            slots, records, rt.canvas_m, rt.canvas_n, impl=impl)
         sharded = False
         if rt.mesh is not None:
             canvases, sharded = shard_canvases(canvases, rt.mesh,
@@ -674,8 +715,7 @@ class DeviceExecutor:
             impl=impl)
         self.n_invocations += 1
         self.n_sharded += bool(sharded)
-        return {"plan": plan, "obj": obj, "boxes": boxes,
-                "patch_out": patch_out, "t0": t0}
+        return {"obj": obj, "boxes": boxes, "patch_out": patch_out}
 
     def _finalize(self, inv: Invocation, payload: dict) -> Completion:
         """Join the device values and do the host-side routing."""
@@ -683,29 +723,46 @@ class DeviceExecutor:
 
         from repro.kernels.stitch import ops as stitch_ops
 
+        tel = self.telemetry
         sync = self.sync or jax.block_until_ready
         plan = payload["plan"]
-        if "fused" in payload:
-            sync(payload["fused"])
-            per_frame = stitch_ops.route_fused(
-                plan, inv.patches, np.asarray(payload["fused"]),
-                obj_threshold=self.obj_threshold)
-            # the unfused evidence (gathered slots) equals the input
-            # crops by construction, so the fused path serves it from
-            # the packed slots it already holds on the host
-            evidence = payload["slots"]
-        else:
-            sync((payload["obj"], payload["patch_out"]))
-            per_frame = stitch_ops.route_detections(
-                plan, inv.patches, np.asarray(payload["obj"]),
-                np.asarray(payload["boxes"]),
-                obj_threshold=self.obj_threshold)
-            evidence = np.asarray(payload["patch_out"])
-        per_frame_pixels: Dict[object, List[np.ndarray]] = {}
-        for i, patch in enumerate(inv.patches):
-            # copy: a view would pin the whole pow2-padded batch in memory
-            per_frame_pixels.setdefault(patch.frame_id, []).append(
-                np.ascontiguousarray(evidence[i, :patch.h, :patch.w]))
+        fused = "fused" in payload
+        with tel.span("tangram.executor.finalize",
+                      inv=payload["inv"]) as finalize:
+            with tel.span("tangram.executor.sync"):
+                sync(payload["fused"] if fused
+                     else (payload["obj"], payload["patch_out"]))
+            with tel.span("tangram.executor.fetch"):
+                if fused:
+                    host = [np.asarray(payload["fused"])]
+                else:
+                    host = [np.asarray(payload[k])
+                            for k in ("obj", "boxes", "patch_out")]
+            got = sum(a.nbytes for a in host)
+            self.bytes_from_device += got
+            finalize.set(bytes_from_device=got)
+            with tel.span("tangram.executor.route"):
+                if fused:
+                    per_frame = stitch_ops.route_fused(
+                        plan, inv.patches, host[0],
+                        obj_threshold=self.obj_threshold)
+                    # the unfused evidence (gathered slots) equals the
+                    # input crops by construction, so the fused path
+                    # serves it from the packed slots it already holds
+                    # on the host
+                    evidence = payload["slots"]
+                else:
+                    per_frame = stitch_ops.route_detections(
+                        plan, inv.patches, host[0], host[1],
+                        obj_threshold=self.obj_threshold)
+                    evidence = host[2]
+                per_frame_pixels: Dict[object, List[np.ndarray]] = {}
+                for i, patch in enumerate(inv.patches):
+                    # copy: a view would pin the whole pow2-padded batch
+                    # in memory
+                    per_frame_pixels.setdefault(patch.frame_id, []).append(
+                        np.ascontiguousarray(
+                            evidence[i, :patch.h, :patch.w]))
         wall = self.clock() - payload["t0"]
 
         self.n_detections += sum(len(v) for v in per_frame.values())
@@ -813,7 +870,7 @@ def make_executor(name: str, **cfg):
 
     cls = lookup("executor", _EXECUTORS, name)
     device_only = {"fuse", "tokens_fn", "embed_kernel", "embed_bias",
-                   "patch", "obj_threshold"}
+                   "patch", "obj_threshold", "telemetry"}
     if cls is SimExecutor:
         drop = {"max_inflight", "models"} | device_only
     elif cls is AsyncDeviceExecutor:
@@ -842,11 +899,18 @@ class ServingEngine:
     (:mod:`repro.sources`) through :meth:`overloaded`, which respond by
     dropping frames or degrading RoI quality.  ``None`` (default)
     disables the signal: trace replay ingests everything, as before.
+
+    ``telemetry`` (a :class:`~repro.core.telemetry.Telemetry`, off by
+    default) records a ``tangram.engine.dispatch`` span around each
+    call into the executor, with the instant the invoker fired at
+    (``t_fire``), the engine time the call began (``t_launch``) and the
+    patches' arrival instants.
     """
 
     def __init__(self, pool, executor, clock: Optional[Clock] = None,
                  check_invariants: bool = False,
-                 ingestion_window: Optional[int] = None):
+                 ingestion_window: Optional[int] = None,
+                 telemetry: Optional[Telemetry] = None):
         if ingestion_window is not None and ingestion_window < 1:
             raise ValueError(f"ingestion_window must be >= 1, got "
                              f"{ingestion_window}")
@@ -855,6 +919,7 @@ class ServingEngine:
         self.clock = clock if clock is not None else VirtualClock()
         self.check_invariants = check_invariants
         self.ingestion_window = ingestion_window
+        self.telemetry = telemetry if telemetry is not None else Telemetry()
         self.backlog_high_water = 0
         self.outcomes: List[PatchOutcome] = []
         self.invocations: List[Invocation] = []
@@ -1063,7 +1128,10 @@ class ServingEngine:
             # handle first, and only block on the oldest when none is
             while len(self._inflight) >= bound:
                 self._resolve_one()
-        handle = self._submit(inv)
+        tel = self.telemetry
+        attrs = self._fire_attrs(inv) if tel.enabled else {}
+        with tel.span("tangram.engine.dispatch", **attrs):
+            handle = self._submit(inv)
         self._event_seq += 1
         handle.seq = self._event_seq
         if handle.model is None:
@@ -1075,6 +1143,18 @@ class ServingEngine:
             self._inflight.append(handle)
             self.inflight_high_water = max(self.inflight_high_water,
                                            len(self._inflight))
+
+    def _fire_attrs(self, inv: Invocation) -> dict:
+        """The dispatch span's attributes: how the invocation fired, and
+        when its patches arrived."""
+        arrivals = []
+        for p in inv.patches:
+            slot = self._slot_of.get(id(p))
+            if slot is not None:
+                arrivals.append(self._slot_t[slot])
+        return {"reason": inv.reason, "patches": len(inv.patches),
+                "canvases": len(inv.canvases), "t_fire": inv.t_submit,
+                "t_launch": self.clock.now(), "arrivals": arrivals}
 
     def _submit(self, inv: Invocation) -> ExecHandle:
         submit = getattr(self.executor, "submit", None)

@@ -490,6 +490,22 @@ class WorkerPoolExecutor:
     def evidence_bytes(self) -> int:
         return self._sum("evidence_bytes")
 
+    @property
+    def bytes_to_device(self) -> int:
+        return self._sum("bytes_to_device")
+
+    @property
+    def bytes_from_device(self) -> int:
+        return self._sum("bytes_from_device")
+
+    @property
+    def slot_pixels(self) -> int:
+        return self._sum("slot_pixels")
+
+    @property
+    def live_pixels(self) -> int:
+        return self._sum("live_pixels")
+
     def worker_stats(self) -> List[dict]:
         """Per-worker counters for ``Results.worker_stats`` / benchmarks."""
         stats = []

@@ -65,8 +65,15 @@ invocations, routed detections and the detections themselves, SLO
 violations, frames still held), so callers can compare runs in one
 process.
 
+``--telemetry PATH`` turns on the span recorder
+(:mod:`repro.core.telemetry`) for the whole run and writes its spans and
+the executors' transfer counters to PATH as Chrome trace-event JSON; the
+summary then also gives the p95 of the engine's fire lag (engine time
+a submit began less the instant the invoker fired at).
+
 Usage:
   PYTHONPATH=src python -m repro.launch.serve --frames 40 --slo 1.0
+  PYTHONPATH=src python -m repro.launch.serve --telemetry spans.json
   PYTHONPATH=src python -m repro.launch.serve --model tangram --full-width \
     --use-pallas-stitch
   PYTHONPATH=src python -m repro.launch.serve --async-device --max-inflight 4
@@ -84,6 +91,7 @@ import time
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro import param as param_lib
 from repro.compat import shardingx
@@ -97,6 +105,7 @@ from repro.core.invoker import SLOAwareInvoker
 from repro.core.latency import LatencyBank, OnlineLatencyTable, measure
 from repro.core.models import make_model
 from repro.core.parallel import ParallelShardedEngine
+from repro.core.telemetry import Telemetry
 from repro.core.fleet import (FleetInvokerPool, FleetPlan, FleetCostModel,
                               ShardedEngine, fleet_uniform_pool,
                               make_planner)
@@ -108,6 +117,13 @@ from repro.launch.mesh import make_serve_mesh, make_worker_meshes
 from repro.models import detector as detector_lib
 from repro.sharding import ShardingConfig
 from repro.sources import RateProfile, make_source
+
+
+#: the device executors' counters, summed over executors into the
+#: ``--telemetry`` file
+COUNTERS = ("n_invocations", "n_fused", "n_sharded", "n_detections",
+            "evidence_bytes", "bytes_to_device", "bytes_from_device",
+            "slot_pixels", "live_pixels")
 
 
 def build_detector(canvas: int = 256, quantize: bool = False):
@@ -280,6 +296,11 @@ def main(argv=None):
                         "--model-map 0.5=vit_s16 --model-map 2.0=tangram; "
                         "repeatable; classes not mapped fall back to "
                         "--model")
+    p.add_argument("--telemetry", metavar="PATH", default=None,
+                   help="record the serve path's spans (dispatch, launch, "
+                        "finalize and their parts) and write them with the "
+                        "transfer counters to PATH as Chrome trace-event "
+                        "JSON at the end (loads in Perfetto)")
     p.add_argument("--online-latency", action="store_true",
                    help="fold observed per-worker completion times back "
                         "into the latency table (EWMA) so firing decisions "
@@ -450,6 +471,7 @@ def main(argv=None):
             max(s.weight_bytes for s in specs.values()),
             {name: (s.weight_bytes, s.load_s) for name, s in specs.items()})
 
+    telemetry = Telemetry(enabled=args.telemetry is not None)
     t_start = time.time()
     shard_executors = None
     if config.shards:
@@ -463,7 +485,7 @@ def main(argv=None):
                 canvas_m=m, canvas_n=n, use_pallas=config.use_pallas,
                 fuse=config.fuse, mesh=meshes[i % len(meshes)],
                 rules=rules, max_inflight=config.max_inflight,
-                obj_threshold=args.obj_threshold,
+                obj_threshold=args.obj_threshold, telemetry=telemetry,
                 models=runtimes(meshes[i % len(meshes)]) if builds else None,
                 **fused_kwargs(cfg, params, rules))
             for i in range(config.shards)]
@@ -479,7 +501,7 @@ def main(argv=None):
                 canvas_m=m, canvas_n=n, use_pallas=config.use_pallas,
                 fuse=config.fuse, mesh=meshes[i], rules=rules,
                 max_inflight=config.max_inflight,
-                obj_threshold=args.obj_threshold,
+                obj_threshold=args.obj_threshold, telemetry=telemetry,
                 models=runtimes(meshes[i]) if builds else None,
                 **fused_kwargs(cfg, params, rules)),
             placement=make_placement(config.placement),
@@ -490,7 +512,7 @@ def main(argv=None):
             canvas_m=m, canvas_n=n, use_pallas=config.use_pallas,
             fuse=config.fuse, mesh=mesh, rules=rules,
             max_inflight=config.max_inflight,
-            obj_threshold=args.obj_threshold,
+            obj_threshold=args.obj_threshold, telemetry=telemetry,
             models=runtimes(mesh) if builds else None,
             **fused_kwargs(cfg, params, rules))
         if config.online_latency or caches is not None:
@@ -541,7 +563,7 @@ def main(argv=None):
         shard_engines = [
             ServingEngine(build_pool(fleet=True), shard_executors[s],
                           clock=shard_clocks[s],
-                          ingestion_window=window)
+                          ingestion_window=window, telemetry=telemetry)
             for s in range(config.shards)]
         if hasattr(source, "camera_rates"):
             planner = make_planner(
@@ -559,7 +581,8 @@ def main(argv=None):
         engine = ServingEngine(build_pool(), executor,
                                clock=make_clock(config.clock,
                                                 speed=config.wall_speed),
-                               ingestion_window=config.ingestion_window)
+                               ingestion_window=config.ingestion_window,
+                               telemetry=telemetry)
     outcomes = engine.serve(source)
 
     stats = source.stats()
@@ -569,6 +592,14 @@ def main(argv=None):
     def _total(attr: str) -> int:
         return sum(getattr(e, attr, 0) for e in executors)
 
+    fire_lag_ms = None
+    if telemetry.enabled:
+        lags = [r["t_launch"] - r["t_fire"]
+                for r in telemetry.invocations().values() if "t_fire" in r]
+        if lags:
+            fire_lag_ms = 1e3 * float(np.percentile(lags, 95))
+        telemetry.write_chrome_trace(args.telemetry, counters={
+            attr: _total(attr) for attr in COUNTERS})
     if config.shards:
         overlap = (f"{config.shards} shard(s), "
                    f"{config.planner or 'cost'} planner"
@@ -595,7 +626,11 @@ def main(argv=None):
           f"data={axis_sizes.get('data', 1)}), "
           f"routed {_total('n_detections')} detections + "
           f"{_total('evidence_bytes') / 1e6:.2f} MB patch evidence back to "
-          f"frames, {violated} SLO violations "
+          f"frames, {_total('bytes_to_device') / 1e6:.2f} MB to and "
+          f"{_total('bytes_from_device') / 1e6:.2f} MB from the device"
+          + (f", fire lag p95 {fire_lag_ms:.1f} ms"
+             if fire_lag_ms is not None else "") +
+          f", {violated} SLO violations "
           f"({len(executor.frames)} frames still held, "
           f"{time.time()-t_start:.1f}s wall)")
     if config.shards:
@@ -638,6 +673,9 @@ def main(argv=None):
             "fused": _total("n_fused"),
             "sharded": _total("n_sharded"),
             "detections": _total("n_detections"),
+            "mb_to_device": _total("bytes_to_device") / 1e6,
+            "mb_from_device": _total("bytes_from_device") / 1e6,
+            "fire_lag_p95_ms": fire_lag_ms,
             "routed": routed,
             "violations": violated,
             "frames_held": len(executor.frames)}

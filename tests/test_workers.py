@@ -241,7 +241,10 @@ def test_pool_routes_identical_detections_to_plain_async(n_workers):
 
     assert plain_cap, "trace produced no detections to compare"
     assert _sorted_dets(pool_cap) == _sorted_dets(plain_cap)
-    assert pool.n_detections == plain.n_detections
+    for attr in ("n_detections", "bytes_to_device", "bytes_from_device",
+                 "slot_pixels", "live_pixels"):
+        assert getattr(pool, attr) == getattr(plain, attr), attr
+    assert plain.bytes_to_device > 0 and plain.slot_pixels > 0
     # shared frame store fully drained even when different workers route
     # different patches of the same frame
     assert pool.frames == {}
