@@ -490,18 +490,18 @@ class ModelRuntime:
 
 
 class DeviceExecutor:
-    """Executor over the real pipeline: batched stitch -> (data-parallel)
+    """Executor over the real pipeline: stitch -> (data-parallel)
     detect -> inverse unstitch -> per-frame routing, joined synchronously
     at submit (``t_finish`` = ``t_submit`` + measured wall execution, the
     same quantity the offline profiling table estimates, so SLO
     accounting stays consistent between simulation and device).
 
     The pipeline is split into :meth:`_launch` (host-side crop gather +
-    slot packing + jit dispatch — *returns before the device finishes*,
-    courtesy of JAX async dispatch) and :meth:`_finalize` (block on the
-    device values, route detections, account).  This class joins the two
-    back-to-back; :class:`AsyncDeviceExecutor` keeps them apart so device
-    execution overlaps arrival ingestion.
+    stitch or slot packing + jit dispatch — *returns before the device
+    finishes*, courtesy of JAX async dispatch) and :meth:`_finalize`
+    (block on the device values, route detections, account).  This
+    class joins the two back-to-back; :class:`AsyncDeviceExecutor` keeps
+    them apart so device execution overlaps arrival ingestion.
 
     Owns the frame store: ``add_frame`` registers a frame's pixels with a
     reference count (how many patches were cut from it); the engine's
@@ -513,18 +513,31 @@ class DeviceExecutor:
     ``jax.block_until_ready``); tests and benchmarks substitute a hook
     that also joins non-JAX future-likes.
 
+    Which pixel buffer crosses to the device depends on the path.  The
+    unfused path stitches the crops onto the (B, M, N, 3) float32 canvas
+    batch on the host (``stitch_ops.stitch_plan_host``, into a buffer
+    reused once the invocation that last used it is joined) and sends
+    that batch, padded to the mesh's data axis; the trunk and the
+    unstitch read it on the device.  The fused path (``fuse`` on a runtime with
+    the fused fields) sends the pow2-padded patch slots
+    (``stitch_ops.pack_plan_host``), which its stitch->embed kernel
+    assembles into canvases in VMEM.  Both send the plan's records.
+
     ``obj_threshold`` is the objectness a grid cell needs to be routed
-    as a detection.  ``use_pallas`` runs the unfused stitch/unstitch as
-    Pallas kernels, and ``fuse`` the fused stitch->embed and
-    decode->gather kernels; ``stitch_ops.pallas_impl`` compiles them on
-    TPU and interprets them on CPU.
+    as a detection.  ``use_pallas`` runs the unfused path's unstitch as
+    a Pallas kernel (its stitch runs on the host), and ``fuse`` the
+    fused stitch->embed and decode->gather kernels;
+    ``stitch_ops.pallas_impl`` compiles them on TPU and interprets them
+    on CPU.
 
     ``telemetry`` (a :class:`~repro.core.telemetry.Telemetry`, off by
     default) records the launch and finalize spans and their parts.  The
-    transfer counters are always on: ``bytes_to_device`` (slots and
-    records), ``bytes_from_device`` (every array fetched),
-    ``slot_pixels`` (slot capacity x H x W) and ``live_pixels`` (the
-    patches' own h x w).
+    counters are always on: ``n_host_stitched`` and ``n_fused`` (the
+    invocations that took each path), ``bytes_to_device`` (the pixel
+    buffer and the records), ``bytes_from_device`` (every array
+    fetched), ``slot_pixels`` (the pixel buffer's pixels: rows x M x N
+    of the canvas batch, or slot capacity x H x W of the slots) and
+    ``live_pixels`` (the patches' own h x w).
 
     Multi-model serving: ``models`` maps a registry model name to a
     :class:`ModelRuntime` — or to a zero-arg callable returning one,
@@ -563,9 +576,12 @@ class DeviceExecutor:
         self.obj_threshold = obj_threshold
         self.telemetry = telemetry if telemetry is not None else Telemetry()
         self._runtimes: Dict[Optional[str], ModelRuntime] = {}
+        # host canvas batches, by shape, whose last invocation is joined
+        self._free_canvases: Dict[tuple, List[np.ndarray]] = {}
         self.store = FrameStore()
         self.n_invocations = 0
         self.n_fused = 0
+        self.n_host_stitched = 0
         self.n_detections = 0
         self.n_sharded = 0
         self.evidence_bytes = 0
@@ -625,10 +641,20 @@ class DeviceExecutor:
 
     # --------------------------------------------------------- execution ----
 
+    def _fuses(self, rt: ModelRuntime) -> bool:
+        """Whether ``rt``'s invocations take the fused hot path: a
+        ``fuse=True`` executor on a runtime that has the fused fields."""
+        return (self.fuse and rt.tokens_fn is not None
+                and rt.embed_kernel is not None and rt.patch is not None)
+
     def _launch(self, inv: Invocation) -> dict:
         """Host-side stitch + jit dispatch.  Everything here returns as
         soon as the work is *enqueued* on the device (JAX async
-        dispatch); nothing blocks on device values."""
+        dispatch); nothing blocks on device values.
+
+        The pixels cross to the device as the fused path's padded slots,
+        or, on the unfused path, as the canvas batch stitched here on
+        the host (put straight onto the mesh's data sharding, if any)."""
         # imported here so the pure-simulation control plane never touches
         # the kernel/jit stack
         import jax.numpy as jnp
@@ -640,6 +666,7 @@ class DeviceExecutor:
         with tel.span("tangram.executor.launch") as launch:
             rt = self._runtime(inv.model)
             plan = inv.batch_plan()
+            fused = self._fuses(rt)
             with tel.span("tangram.executor.gather"):
                 crops = []
                 store = self.store
@@ -651,13 +678,23 @@ class DeviceExecutor:
                     else:
                         crops.append(
                             frame[patch.y0:patch.y1, patch.x0:patch.x1])
-            with tel.span("tangram.executor.pack"):
-                slots = stitch_ops.pack_plan_host(crops, plan)
+            with tel.span("tangram.executor.pack",
+                          layout="slots" if fused else "canvas"):
+                if fused:
+                    pixels = stitch_ops.pack_plan_host(crops, plan)
+                else:
+                    pixels = stitch_ops.stitch_plan_host(
+                        crops, plan, out=self._canvas_buffer(plan, rt))
             with tel.span("tangram.executor.put"):
-                slots_d = jnp.asarray(slots)
+                if not fused and rt.mesh is not None:
+                    pixels_d, sharded = shard_canvases(pixels, rt.mesh,
+                                                       rt.rules)
+                    self.n_sharded += bool(sharded)
+                else:
+                    pixels_d = jnp.asarray(pixels)
                 records = jnp.asarray(plan.records)
-            sent = slots_d.nbytes + records.nbytes
-            slot_px = slots.shape[0] * slots.shape[1] * slots.shape[2]
+            sent = pixels_d.nbytes + records.nbytes
+            slot_px = pixels.shape[0] * pixels.shape[1] * pixels.shape[2]
             live_px = sum(p.h * p.w for p in inv.patches)
             self.bytes_to_device += sent
             self.slot_pixels += slot_px
@@ -665,19 +702,34 @@ class DeviceExecutor:
             launch.set(bytes_to_device=sent, slot_pixels=slot_px,
                        live_pixels=live_px)
             with tel.span("tangram.executor.enqueue"):
-                out = self._enqueue(rt, plan, slots_d, records)
+                out = self._enqueue(rt, plan, pixels_d, records, fused)
         out.update(plan=plan, t0=t0, inv=launch.inv)
-        if "fused" in out:
-            out["slots"] = slots
+        out["slots" if fused else "canvases"] = pixels
         return out
 
-    def _enqueue(self, rt: ModelRuntime, plan, slots, records) -> dict:
-        """The jit calls of one invocation, on device-resident slots and
-        records: the device values ``_finalize`` joins."""
+    def _canvas_buffer(self, plan, rt: ModelRuntime) -> np.ndarray:
+        """A host canvas batch for ``plan``, its rows padded to the mesh's
+        data axis: one a joined invocation left, or a new one.  Reuse
+        matters: every first write to a new buffer's pages faults."""
+        rows = plan.num_canvases
+        if rt.mesh is not None:
+            from repro.compat import shardingx
+
+            rows += -rows % shardingx.mesh_axis_sizes(rt.mesh).get("data", 1)
+        shape = (rows, plan.canvas_m, plan.canvas_n, 3)
+        try:
+            return self._free_canvases.get(shape, []).pop()
+        except IndexError:
+            return np.zeros(shape, np.float32)
+
+    def _enqueue(self, rt: ModelRuntime, plan, pixels, records,
+                 fused: bool) -> dict:
+        """The jit calls of one invocation, on device-resident pixels
+        (slots when ``fused``, else the canvas batch) and records: the
+        device values ``_finalize`` joins."""
         from repro.kernels.stitch import ops as stitch_ops
 
-        if self.fuse and rt.tokens_fn is not None \
-                and rt.embed_kernel is not None and rt.patch is not None:
+        if fused:
             # fused hot path: stitch->patch-embed emits the token batch
             # directly (no canvas batch in HBM), the trunk runs from
             # tokens, and decode+gather lands straight in per-patch slot
@@ -687,22 +739,15 @@ class DeviceExecutor:
             # fused path exists only as Pallas kernels.
             impl = stitch_ops.pallas_impl()
             tokens = stitch_ops.stitch_embed(
-                slots, records, rt.embed_kernel, rt.embed_bias,
+                pixels, records, rt.embed_kernel, rt.embed_bias,
                 rt.canvas_m, rt.canvas_n, rt.patch, impl=impl)
             raw = rt.tokens_fn(rt.params, tokens)
-            fused = stitch_ops.unstitch_decode(
+            out = stitch_ops.unstitch_decode(
                 raw, records, rt.patch, plan.slot_capacity, impl=impl)
             self.n_invocations += 1
             self.n_fused += 1
-            return {"fused": fused}
-        impl = stitch_ops.pallas_impl() if self.use_pallas else "xla"
-        canvases = stitch_ops.stitch_canvases(
-            slots, records, rt.canvas_m, rt.canvas_n, impl=impl)
-        sharded = False
-        if rt.mesh is not None:
-            canvases, sharded = shard_canvases(canvases, rt.mesh,
-                                               rt.rules)
-        obj, boxes = rt.serve_fn(rt.params, canvases)
+            return {"fused": out}
+        obj, boxes = rt.serve_fn(rt.params, pixels)
         # inverse gather, grouped by source frame alongside the routed
         # detections.  The box head has no pixel-space output, so the
         # canvases stand in for a per-pixel head (e.g. segmentation): the
@@ -710,11 +755,12 @@ class DeviceExecutor:
         # exercising the unstitch path every invocation.  slot_capacity
         # (pow2-bucketed) keeps the jit static shapes stable across
         # invocations; rows past num_patches are never read.
+        impl = stitch_ops.pallas_impl() if self.use_pallas else "xla"
         patch_out = stitch_ops.unstitch_patches(
-            canvases, records, plan.slot_capacity, plan.hmax, plan.wmax,
+            pixels, records, plan.slot_capacity, plan.hmax, plan.wmax,
             impl=impl)
         self.n_invocations += 1
-        self.n_sharded += bool(sharded)
+        self.n_host_stitched += 1
         return {"obj": obj, "boxes": boxes, "patch_out": patch_out}
 
     def _finalize(self, inv: Invocation, payload: dict) -> Completion:
@@ -738,6 +784,11 @@ class DeviceExecutor:
                 else:
                     host = [np.asarray(payload[k])
                             for k in ("obj", "boxes", "patch_out")]
+            if not fused:
+                # the outputs are on the host, so nothing on the device
+                # reads the host canvas batch any more
+                buf = payload["canvases"]
+                self._free_canvases.setdefault(buf.shape, []).append(buf)
             got = sum(a.nbytes for a in host)
             self.bytes_from_device += got
             finalize.set(bytes_from_device=got)
@@ -828,8 +879,11 @@ def shard_canvases(canvases, mesh, rules):
     never reference pad rows, so the detector output for them is simply
     ignored), then device_put with the batch axis split over "data".
     Pow2-style padding also stabilises jit static shapes: every batch
-    compiles to a multiple of the axis size.  Returns the sharded batch
-    and whether the data axis actually split it (False on 1 device).
+    compiles to a multiple of the axis size.  A host (numpy) batch that
+    already has the pad rows (``DeviceExecutor`` stitches them in)
+    crosses to the device once, straight onto the sharding.  Returns the
+    sharded batch and whether the data axis actually split it (False on
+    1 device).
     """
     import jax
     import jax.numpy as jnp

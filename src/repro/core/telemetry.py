@@ -25,8 +25,10 @@ The serve path's spans (``tangram.`` prefix):
   attributes ``reason``, ``patches``, ``canvases``, ``t_fire`` (the engine
   instant the invoker fired at), ``t_launch`` (engine time when submit
   began) and ``arrivals`` (each patch's arrival instant);
-* ``tangram.executor.launch`` with ``gather`` (crop loop), ``pack`` (slot
-  packing), ``put`` (host-to-device copies) and ``enqueue`` (jit calls);
+* ``tangram.executor.launch`` with ``gather`` (crop loop), ``pack`` (the
+  host stitch onto canvases, or the fused path's slot packing; attribute
+  ``layout``, ``canvas`` or ``slots``), ``put`` (host-to-device copies)
+  and ``enqueue`` (jit calls);
   attributes ``slot_pixels``, ``live_pixels``, ``bytes_to_device``;
 * ``tangram.executor.finalize`` with ``sync`` (joining the device),
   ``fetch`` (device-to-host copies) and ``route`` (routing and the

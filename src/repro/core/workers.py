@@ -487,6 +487,14 @@ class WorkerPoolExecutor:
         return self._sum("n_sharded")
 
     @property
+    def n_fused(self) -> int:
+        return self._sum("n_fused")
+
+    @property
+    def n_host_stitched(self) -> int:
+        return self._sum("n_host_stitched")
+
+    @property
     def evidence_bytes(self) -> int:
         return self._sum("evidence_bytes")
 

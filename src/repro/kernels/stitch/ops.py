@@ -99,6 +99,8 @@ def pack_plan_host(frame_pixels: Sequence[np.ndarray],
     frame_pixels[i] is the (h, w, C) crop for queue patch i.  Returns
     patch_pixels (slot_capacity, hmax, wmax, C) float32, zero-padded —
     the pow2-bucketed capacity keeps jit shapes stable across invocations.
+    The fused device path sends these slots (its kernel stitches them in
+    VMEM); the unfused path sends :func:`stitch_plan_host`'s canvases.
     """
     c = frame_pixels[0].shape[-1] if frame_pixels else 3
     slots = np.zeros((plan.slot_capacity, plan.hmax, plan.wmax, c),
@@ -108,6 +110,38 @@ def pack_plan_host(frame_pixels: Sequence[np.ndarray],
         assert h <= plan.hmax and w <= plan.wmax, (h, w, plan.hmax, plan.wmax)
         slots[i, :h, :w] = px
     return slots
+
+
+def stitch_plan_host(frame_pixels: Sequence[np.ndarray], plan: BatchPlan,
+                     out: np.ndarray | None = None) -> np.ndarray:
+    """Host stitch: copy patch crops straight onto the plan's canvases.
+
+    frame_pixels[i] is the (h, w, C) crop for queue patch i.  Returns the
+    (B, M, N, C) float32 canvas batch that :func:`stitch_canvases` builds
+    on the device from :func:`pack_plan_host`'s slots, bit for bit: each
+    valid record's slot (zero past its crop) lands at (y, x), clipped to
+    its slot and the canvas, and the rest is zero.  ``out``, a (rows >=
+    B, M, N, C) float32 buffer, is zeroed and stitched into in place of a
+    new batch; its rows past B (a data-parallel mesh's pad) stay zero.
+    """
+    m, n = plan.canvas_m, plan.canvas_n
+    if out is None:
+        c = frame_pixels[0].shape[-1] if frame_pixels else 3
+        out = np.zeros((plan.num_canvases, m, n, c), np.float32)
+    else:
+        assert out.shape[0] >= plan.num_canvases, (out.shape, plan)
+        out.fill(0.0)
+    for bi, slot, x, y, w, h in plan.placements():
+        h = min(h, plan.hmax, m - y)
+        w = min(w, plan.wmax, n - x)
+        px = (frame_pixels[slot] if slot < len(frame_pixels)
+              else out[bi, :0, :0])
+        ph, pw = min(h, px.shape[0]), min(w, px.shape[1])
+        if (ph, pw) != (h, w):
+            # the slot's zero padding past a short crop (or an empty slot)
+            out[bi, y:y + h, x:x + w] = 0.0
+        out[bi, y:y + ph, x:x + pw] = px[:ph, :pw]
+    return out
 
 
 def route_detections(plan: BatchPlan, patches: Sequence[Patch],

@@ -121,9 +121,9 @@ from repro.sources import RateProfile, make_source
 
 #: the device executors' counters, summed over executors into the
 #: ``--telemetry`` file
-COUNTERS = ("n_invocations", "n_fused", "n_sharded", "n_detections",
-            "evidence_bytes", "bytes_to_device", "bytes_from_device",
-            "slot_pixels", "live_pixels")
+COUNTERS = ("n_invocations", "n_fused", "n_host_stitched", "n_sharded",
+            "n_detections", "evidence_bytes", "bytes_to_device",
+            "bytes_from_device", "slot_pixels", "live_pixels")
 
 
 def build_detector(canvas: int = 256, quantize: bool = False):
@@ -220,8 +220,9 @@ def main(argv=None):
                         "ingestion window: drop frames, degrade RoI "
                         "quality (drops at 2x), or ignore")
     p.add_argument("--use-pallas-stitch", action="store_true",
-                   help="assemble canvases with the Pallas kernels "
-                        "(compiled on TPU, interpreted on CPU)")
+                   help="run the unfused path's unstitch as a Pallas "
+                        "kernel (compiled on TPU, interpreted on CPU); "
+                        "its canvases are stitched on the host")
     p.add_argument("--obj-threshold", type=float, default=0.5,
                    help="objectness a detector cell needs to be routed "
                         "back to its frame as a detection")

@@ -63,14 +63,19 @@ def run_one(tiny, cls=DeviceExecutor, fuse=False, telemetry=None):
     return ex, inv
 
 
-def plan_counts(inv):
-    """What the plan's shapes give: (slot pixels, live pixels, bytes to
-    the device) of one invocation's float32 RGB slots and int32 records."""
+def plan_counts(inv, fused=False):
+    """What the plan's shapes give: (pixels sent, live pixels, bytes to
+    the device) of one invocation's float32 RGB pixel buffer and int32
+    records.  The buffer is the canvas batch on the unfused path and the
+    pow2-padded slots on the fused one."""
     plan = inv.batch_plan()
-    slot_px = plan.slot_capacity * plan.hmax * plan.wmax
+    if fused:
+        sent_px = plan.slot_capacity * plan.hmax * plan.wmax
+    else:
+        sent_px = plan.num_canvases * plan.canvas_m * plan.canvas_n
     live_px = sum(p.h * p.w for p in inv.patches)
     records = np.asarray(plan.records)
-    return slot_px, live_px, slot_px * 3 * 4 + records.size * 4
+    return sent_px, live_px, sent_px * 3 * 4 + records.size * 4
 
 
 def test_disabled_recorder_records_nothing_and_counters_count(tiny):
@@ -82,6 +87,20 @@ def test_disabled_recorder_records_nothing_and_counters_count(tiny):
     assert (ex.slot_pixels, ex.live_pixels, ex.bytes_to_device) == \
         (slot_px, live_px, to_dev)
     assert ex.bytes_from_device > 0 and ex.n_invocations == 1
+
+
+@pytest.mark.parametrize("fuse", [False, True], ids=["canvas", "slots"])
+def test_counters_count_the_buffer_each_path_sends(tiny, fuse):
+    """The unfused path sends the canvas batch and counts an invocation
+    in ``n_host_stitched``; the fused one sends the slots and counts it in
+    ``n_fused``; the pack span names the layout."""
+    tel = Telemetry(enabled=True)
+    ex, inv = run_one(tiny, fuse=fuse, telemetry=tel)
+    assert (ex.slot_pixels, ex.live_pixels, ex.bytes_to_device) == \
+        plan_counts(inv, fused=fuse)
+    assert (ex.n_host_stitched, ex.n_fused) == (int(not fuse), int(fuse))
+    pack = [s[7] for s in tel.spans if s[3] == P + "pack"]
+    assert pack == [{"layout": "slots" if fuse else "canvas"}]
 
 
 @pytest.mark.parametrize("cls, fuse", [
@@ -125,7 +144,7 @@ def test_transfer_counters_are_the_arrays_nbytes(tiny, fuse):
               [payload[k] for k in ("obj", "boxes", "patch_out")])
     want_from = sum(np.asarray(a).nbytes for a in device)
     ex._finalize(inv, payload)
-    assert ex.bytes_to_device == plan_counts(inv)[2]
+    assert ex.bytes_to_device == plan_counts(inv, fused=fuse)[2]
     assert ex.bytes_from_device == want_from
 
 
@@ -144,11 +163,31 @@ def small_serve_fn(params, x):
     return (jnp.zeros((x.shape[0], 2, 2)), jnp.zeros((x.shape[0], 2, 2, 4)))
 
 
+def small_tokens_fn(params, tokens):
+    import jax.numpy as jnp
+    return jnp.zeros((tokens.shape[0], 2, 2, 5))
+
+
+#: the fused path's fields for ``small_tokens_fn`` on 64 x 64 canvases
+SMALL_FUSED = dict(fuse=True, tokens_fn=small_tokens_fn,
+                   embed_kernel=np.zeros((32 * 32 * 3, 8), np.float32),
+                   embed_bias=np.zeros((8,), np.float32), patch=32)
+
+
 def test_xfer_and_slot_fill_equal_the_plans_per_invocation():
     """Per invocation, the spans' transfer and pixel attributes are what
     each plan's shapes give, and they sum to the executor's counters."""
+    check_counts_per_invocation(fused=False)
+
+
+def test_xfer_and_slot_fill_equal_the_plans_per_invocation_fused():
+    check_counts_per_invocation(fused=True)
+
+
+def check_counts_per_invocation(fused):
     tel = Telemetry(enabled=True)
-    ex = DeviceExecutor(small_serve_fn, None, 64, 64, telemetry=tel)
+    ex = DeviceExecutor(small_serve_fn, None, 64, 64, telemetry=tel,
+                        **(SMALL_FUSED if fused else {}))
     trace = device_trace()
     for fid in {p.frame_id for p in trace}:
         ex.add_frame(fid, np.ones((64, 64, 3), np.float32),
@@ -163,7 +202,7 @@ def test_xfer_and_slot_fill_equal_the_plans_per_invocation():
     for i, inv in enumerate(eng.invocations):
         row = rows[i]
         assert (row["slot_pixels"], row["live_pixels"],
-                row["bytes_to_device"]) == plan_counts(inv)
+                row["bytes_to_device"]) == plan_counts(inv, fused=fused)
         assert row["patches"] == len(inv.patches)
         assert row["t_fire"] == inv.t_submit
         assert len(row["arrivals"]) == len(inv.patches)
@@ -171,6 +210,8 @@ def test_xfer_and_slot_fill_equal_the_plans_per_invocation():
     for attr in ("slot_pixels", "live_pixels", "bytes_to_device",
                  "bytes_from_device"):
         assert sum(r[attr] for r in rows.values()) == getattr(ex, attr)
+    n = len(eng.invocations)
+    assert (ex.n_host_stitched, ex.n_fused) == ((0, n) if fused else (n, 0))
 
 
 class _Instant:

@@ -241,10 +241,14 @@ def test_pool_routes_identical_detections_to_plain_async(n_workers):
 
     assert plain_cap, "trace produced no detections to compare"
     assert _sorted_dets(pool_cap) == _sorted_dets(plain_cap)
-    for attr in ("n_detections", "bytes_to_device", "bytes_from_device",
-                 "slot_pixels", "live_pixels"):
+    for attr in ("n_detections", "n_host_stitched", "n_fused",
+                 "bytes_to_device", "bytes_from_device", "slot_pixels",
+                 "live_pixels"):
         assert getattr(pool, attr) == getattr(plain, attr), attr
     assert plain.bytes_to_device > 0 and plain.slot_pixels > 0
+    # every invocation crossed as a host-stitched canvas batch
+    assert pool.n_host_stitched == pool.n_invocations > 0
+    assert pool.n_fused == 0
     # shared frame store fully drained even when different workers route
     # different patches of the same frame
     assert pool.frames == {}
