@@ -93,6 +93,8 @@ class Run:
     t_trace: float = math.inf       # engine time the profiler started
     trace: Optional[dict] = None
     trace_bytes: int = 0
+    #: the program's span recorder over the whole window (traced runs)
+    telemetry: Optional[object] = None
 
     def backlog_growth(self) -> float:
         """Mean backlog over the window's last quarter less that over its
@@ -155,7 +157,7 @@ class Harness:
 
     # ------------------------------------------------------------ pieces ----
 
-    def make_executor(self, spans=None):
+    def make_executor(self, spans=None, telemetry=None):
         """The executor as ``serve.main`` makes it for this deployment."""
         from repro.core.engine import make_executor
 
@@ -165,7 +167,8 @@ class Harness:
             params=self.params, canvas_m=self.m, canvas_n=self.m,
             use_pallas=d["use_pallas"], fuse=d["fuse"], mesh=self.mesh,
             rules=self.rules, max_inflight=d["max_inflight"],
-            obj_threshold=self.threshold, **self.fused)
+            obj_threshold=self.threshold, telemetry=telemetry,
+            **self.fused)
         if spans is not None:
             spans.hook_sync(ex)
         return ex
@@ -278,6 +281,7 @@ class Harness:
 
         from repro.core.clock import WallClock
         from repro.core.engine import ServingEngine
+        from repro.core.telemetry import Telemetry
 
         t = time.perf_counter()
         tr, arrivals = self.arrivals(seed, seconds, fps_scale)
@@ -305,7 +309,11 @@ class Harness:
         planned = [_key(i) for i in plan_invs]
 
         spans = Spans()
-        executor = self.make_executor(spans)
+        # a traced run records the program's own spans over the whole
+        # window, for the per-layer readers; the untraced runs that give
+        # the end-to-end numbers keep the recorder off, as served
+        tel = Telemetry(enabled=True) if trace else None
+        executor = self.make_executor(spans, telemetry=tel)
         self.register_frames(executor, arrivals)
         pool = self.make_pool()
         kept = {}
@@ -322,7 +330,7 @@ class Harness:
         # the window's engine time starts with its clock
         clock = WallClock(sleep_fn=spans.sleep)
         state.clock = clock
-        engine = ServingEngine(pool, executor, clock=clock)
+        engine = ServingEngine(pool, executor, clock=clock, telemetry=tel)
         compiles.start()
         for a in arrivals:
             if trace and trace_dir is None and a.t_arrive >= t_trace:
@@ -348,7 +356,8 @@ class Harness:
             invocations=state.records, compiles_in_window=compiles.count,
             late_s=np.array(state.late), t_trace=t_traced,
             backlog=[(t, state.backlog_at(t)) for t in
-                     np.linspace(0.25 * seconds, seconds, 16)])
+                     np.linspace(0.25 * seconds, seconds, 16)],
+            telemetry=tel)
         if trace_dir is not None:
             run.trace, run.trace_bytes = spans.reduce(trace_dir)
         if state.mismatched:

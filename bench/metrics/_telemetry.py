@@ -3,7 +3,7 @@ its spans and counters.
 
 A run holds the recorder as ``run.telemetry`` (a
 ``repro.core.telemetry.Telemetry`` that recorded the whole window) only
-where the harness turned it on; without one every such reader gives
+in a traced run (``--trace 1``); without one every such reader gives
 None.  The recorder numbers invocations from 0 in the order the engine
 dispatched them, as the harness numbers ``InvRecord.ordinal``.  Readers
 take the invocations outside the profiler's trace, since profiling

@@ -10,7 +10,9 @@ the trace's one clock.
 Device planes are ``/device:TPU:<n>``; their ``XLA Modules`` line holds
 one event per program run, named ``<module>(<fingerprint>)``, and their
 ``XLA Ops`` line one event per operation.  Host spans are the
-benchmark's ``TraceAnnotation``s on the main thread's line of ``/host:CPU``.
+``TraceAnnotation``s of the benchmark (``bench.*``) and of the serve
+path's recorder (``tangram.*``, on in a traced run) on the main thread's
+line of ``/host:CPU``.
 Which module is which kernel comes from ``modules.json``.
 """
 from __future__ import annotations
@@ -23,8 +25,9 @@ from pathlib import Path
 from typing import Dict, Iterable, List, Sequence, Tuple
 
 MODULES_FILE = Path(__file__).resolve().parent / "modules.json"
-#: host spans the benchmark opens; the window span bounds the traced window
-SPAN_PREFIX = "bench."
+#: host spans read from the trace: the benchmark's own and the serve
+#: path's (``repro.core.telemetry``); the window span bounds the window
+SPAN_PREFIX = ("bench.", "tangram.")
 WINDOW_SPAN = "bench.window"
 
 
@@ -162,8 +165,8 @@ def kernel_runs(modules: Sequence, table: Dict[str, List[str]], lo: float,
 def idle_gaps(modules: Sequence, host: Sequence, lo: float, hi: float
               ) -> Dict[str, float]:
     """Nanoseconds of the window in which no module ran, split by the
-    innermost benchmark span open at each instant (``no span`` where
-    none was)."""
+    innermost host span open at each instant (``no span`` where none
+    was)."""
     busy = union((s, e) for _, s, e in clip(modules, lo, hi))
     gaps, t = [], lo
     for a, b in busy:

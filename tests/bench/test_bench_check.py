@@ -5,7 +5,6 @@ import numpy as np
 import pytest
 
 from bench import calibrate, run
-from tests.bench.conftest import tiny_config
 
 SEEDS = (2**31 + 101, 2**31 + 102)
 SECONDS = 3.0
@@ -20,7 +19,7 @@ def window(h, seed):
 def test_program_passes_and_fp8_control_fails(tiny_harness, seed):
     h = tiny_harness
     r, kept = window(h, seed)
-    ok, checks = run.judge(h.check(kept, r), tiny_config())
+    ok, checks = run.judge(h.check(kept, r), h.config)
     assert ok, checks
     fp8 = calibrate.controls(h)["fp8"]
     numbers = h.check(kept, r, control=fp8)
@@ -28,7 +27,7 @@ def test_program_passes_and_fp8_control_fails(tiny_harness, seed):
         or numbers["box_gap"] > 2 * checks["box_gap"]["limit"]
     numbers.update(placement_faults=0, evidence_mismatch=0,
                    route_mismatch=0, undelivered=0)
-    ok, _ = run.judge(numbers, tiny_config())
+    ok, _ = run.judge(numbers, h.config)
     assert not ok
 
 
@@ -40,7 +39,7 @@ def test_the_fused_path_passes(tiny_harness, monkeypatch):
     r, kept = window(h, SEEDS[0])
     assert kept and all(k["out"][0] == "raw" for k in kept.values())
     numbers = h.check(kept, r)
-    ok, checks = run.judge(numbers, tiny_config())
+    ok, checks = run.judge(numbers, h.config)
     assert ok, checks
     assert numbers["compared_invocations"] == len(kept)
 
@@ -119,7 +118,7 @@ def test_a_planted_fault_is_not_correct(tiny_harness, monkeypatch, fault):
         monkeypatch.setattr(stitch_ops, "unstitch_patches", bump)
     r, kept = window(h, SEEDS[0])
     numbers = h.check(kept, r)
-    ok, checks = run.judge(numbers, tiny_config())
+    ok, checks = run.judge(numbers, h.config)
     assert not ok, checks
     failing = {k for k, c in checks.items() if c["value"] > c["limit"]}
     want = {"half_batch": {"obj_gap", "box_gap"},

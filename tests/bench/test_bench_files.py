@@ -9,7 +9,9 @@ import subprocess
 import sys
 from pathlib import Path
 
-from tests.bench.conftest import TINY_LIMITS
+import pytest
+
+from tests.bench.conftest import TINY_CELLS, tiny_config, tiny_mix
 
 ROOT = Path(__file__).resolve().parents[2]
 BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
@@ -138,21 +140,18 @@ print(json.dumps(out))
 '''
 
 
-def test_a_cell_is_added_by_files_and_an_entry_alone(tmp_path):
+@pytest.mark.parametrize("base", TINY_CELLS, ids=[c["config"]
+                                                  for c in TINY_CELLS])
+def test_a_cell_is_added_by_files_and_an_entry_alone(tmp_path, base):
     """A new configuration, traffic mix and metric, each a file of its
     own, and one new entry in BENCHMARK.json: the unchanged harness finds
-    them by name and runs the new cell (here on the CPU, at a tiny size)."""
+    them by name and runs the new cell (here on the CPU, at a tiny size,
+    from each configuration's file and its cell's traffic)."""
     shutil.copytree(ROOT / "bench", tmp_path / "bench",
                     ignore=shutil.ignore_patterns("__pycache__"))
-    cfg = json.loads((ROOT / "bench/configs/tangram.json").read_text())
-    cfg.update(name="tiny", limits=TINY_LIMITS, arch=dict(
-        canvas=128, patch=16, n_layers=2, d_model=64, n_heads=4, d_ff=128,
-        param_dtype="bfloat16", compute_dtype="bfloat16"),
-        latency_profile={"batch_sizes": [1, 2, 4], "iters": 2, "warmup": 1})
+    cfg = dict(tiny_config(base["config"]), name="tiny")
     (tmp_path / "bench/configs/tiny.json").write_text(json.dumps(cfg))
-    mix = json.loads((ROOT / "bench/traffic/crowd4k.json").read_text())
-    mix.update(mix="tiny", frame_w=480, frame_h=270, fps_per_camera=2.0,
-               ring_frames=1)
+    mix = dict(tiny_mix(base["traffic"]), mix="tiny")
     (tmp_path / "bench/traffic/tiny4k.json").write_text(json.dumps(mix))
     (tmp_path / "bench/metrics/patches_offered.py").write_text(NEW_METRIC)
     bench = json.loads((ROOT / "BENCHMARK.json").read_text())

@@ -1,6 +1,6 @@
 """The readers of the program's spans and transfer counters: on a
-synthetic run, on a run without the recorder, and on a CPU-sized window
-that recorded them."""
+synthetic run, on a run without the recorder, on a traced CPU-sized
+window, which records them, and on an untraced one, which does not."""
 from types import SimpleNamespace
 
 import numpy as np
@@ -56,46 +56,74 @@ def test_reader_without_the_recorder_reads_nothing(name):
     assert reader(name)(synthetic_run(telemetry=False)) is None
 
 
-def test_a_recorded_window_reads_every_metric(tiny_harness, monkeypatch):
-    """A CPU window with the recorder on for all of it, as a traced run
-    would hand it over: every reader finds a finite value, the transfer
-    readers equal what the window's plans give, and the executor's spans
-    sit inside the harness's own ``submit``/``resolve``/``sync`` times."""
+def _made_executors(monkeypatch):
+    """Every executor the harness makes from here on, with the recorder
+    it was given."""
     import repro.core.engine as engine
-    from repro.core.telemetry import Telemetry
 
-    tel = Telemetry(enabled=True)
-    make, serving = engine.make_executor, engine.ServingEngine
-    made = []
+    make, made = engine.make_executor, []
 
-    def make_recorded(name, **cfg):
-        ex = make(name, telemetry=tel, **cfg)
-        made.append(ex)
+    def spy(name, **cfg):
+        ex = make(name, **cfg)
+        made.append((ex, cfg.get("telemetry")))
         return ex
 
-    monkeypatch.setattr(engine, "make_executor", make_recorded)
-    # the window's engine records; the replay that plans it does not
-    monkeypatch.setattr(
-        engine, "ServingEngine",
-        lambda pool, ex, **kw: serving(
-            pool, ex, telemetry=tel if ex in made else None, **kw))
+    monkeypatch.setattr(engine, "make_executor", spy)
+    return made
+
+
+def _profiler_stub(monkeypatch):
+    """The profiler is the chip's: on the CPU a traced window marks what
+    it would trace and keeps no trace."""
+    from bench import harness
+
+    def start(self):
+        self.tracing = True
+        return "stub"
+
+    def stop(self):
+        self.tracing = False
+
+    monkeypatch.setattr(harness.Spans, "start_trace", start)
+    monkeypatch.setattr(harness.Spans, "stop_trace", stop)
+    monkeypatch.setattr(harness.Spans, "reduce", lambda self, d: (None, 0))
+
+
+def test_a_recorded_window_reads_every_metric(tiny_harness, monkeypatch):
+    """A traced CPU window: the harness gives the window's executor and
+    engine one recorder for all of it, and no other (the warm-up's
+    executor and the replay's engine would take the first invocation
+    ids).  Every reader finds a finite value, the transfer readers equal
+    what the window's plans give, and the executor's spans sit inside
+    the harness's own ``submit``/``resolve``/``sync`` times."""
+    made = _made_executors(monkeypatch)
+    _profiler_stub(monkeypatch)
     h = tiny_harness
     h.reseed(2**31 + 7)
-    # nor the warm-up's executor, whose invocations would take the first
-    # ids (on the CPU the window then compiles its shapes itself)
-    monkeypatch.setattr(h, "warm", lambda invs: 0)
-    run, _kept = h.window(2**31 + 7, 3.0, checked=False)
-    run.telemetry = tel
-    ex = made[-1]
+    run, _kept = h.window(2**31 + 7, 3.0, trace=True, checked=False)
+    tel = run.telemetry
+    assert tel is not None and tel.enabled
+    ex, given = made[-1]
+    assert given is tel
+    assert all(t is None for _, t in made[:-1])
     assert len(tel.invocations()) == len(run.invocations) > 1
+    assert any(r.traced for r in run.invocations)
+    assert sum(not r.traced for r in run.invocations) > 1
     values = {n: reader(n)(run) for n in NAMES}
-    assert all(np.isfinite(v) for v in values.values()), values
-    patches = sum(r.patches for r in run.invocations)
-    assert values["xfer_mb_per_patch"] == pytest.approx(
-        (ex.bytes_to_device + ex.bytes_from_device) / 1e6 / patches)
-    assert values["slot_fill"] == pytest.approx(
-        100.0 * ex.live_pixels / ex.slot_pixels)
+    assert all(v is not None and np.isfinite(v)
+               for v in values.values()), values
     rows = tel.invocations()
+    untraced = [r for r in run.invocations if not r.traced]
+    patches = sum(r.patches for r in untraced)
+    moved = sum(rows[r.ordinal]["bytes_to_device"]
+                + rows[r.ordinal]["bytes_from_device"] for r in untraced)
+    assert values["xfer_mb_per_patch"] == pytest.approx(moved / 1e6
+                                                        / patches)
+    assert 0 < values["slot_fill"] <= 100
+    assert sum(row["bytes_to_device"] for row in rows.values()) == \
+        pytest.approx(ex.bytes_to_device)
+    assert sum(row["slot_pixels"] for row in rows.values()) == \
+        ex.slot_pixels
     for rec in run.invocations:
         row = rows[rec.ordinal]
         assert row["patches"] == rec.patches
@@ -103,3 +131,17 @@ def test_a_recorded_window_reads_every_metric(tiny_harness, monkeypatch):
         assert row["sync_s"] >= rec.sync_s
         assert row["launch_s"] + row["finalize_s"] <= \
             rec.submit_s + rec.resolve_s
+
+
+def test_an_untraced_window_keeps_the_recorder_off(tiny_harness,
+                                                   monkeypatch):
+    """The untraced runs give the end-to-end numbers: no executor gets a
+    recorder, the run holds none, and the span readers read nothing."""
+    made = _made_executors(monkeypatch)
+    h = tiny_harness
+    h.reseed(2**31 + 8)
+    run, _kept = h.window(2**31 + 8, 2.0, checked=False)
+    assert run.telemetry is None
+    assert made and all(t is None for _, t in made)
+    assert all(not ex.telemetry.enabled for ex, _ in made)
+    assert all(reader(n)(run) is None for n in NAMES)

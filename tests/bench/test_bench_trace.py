@@ -102,3 +102,47 @@ def test_per_patch_readers_skip_patches_due_after_the_trace_began(
     assert reader(name)(run) == pytest.approx(untraced)
     run.t_trace = 19.5                              # the last is due after
     assert reader(name)(run) == pytest.approx(traced)
+
+
+def _traced_run(fixture, drop=()):
+    """One traced invocation of 2 canvases and 300000 live pixels on the
+    fixture's trace, without the modules named in ``drop``."""
+    from types import SimpleNamespace
+
+    from bench.harness import InvRecord
+    from bench.peaks import peaks
+
+    fx = json.loads(json.dumps(fixture))
+    for dev in fx["devices"].values():
+        dev["modules"] = [m for m in dev["modules"]
+                          if trace.module_name(m[0]) not in drop]
+    inv = InvRecord(ordinal=0, canvases=2, patches=9, used_area=300000,
+                    canvas_area=2 * 1024 ** 2, t_slack=0.0,
+                    live_pixels=300000, traced=True)
+    return SimpleNamespace(invocations=[inv], arch={"canvas": 1024},
+                           peak=peaks("TPU v5 lite"),
+                           trace=trace.reduce(fx, trace.load_modules()))
+
+
+@pytest.mark.parametrize("drop, nbytes, ns", [
+    # both programs ran: each one's bytes over both one's times
+    ((), 300000 * 12 + 2 * 1024 ** 2 * 12 + 2 * 300000 * 12,
+     878200 + 1413620),
+    # the unfused path: the unstitch alone ran, and only its bytes count
+    (("jit_stitch_canvases",), 2 * 300000 * 12, 1413620),
+], ids=["stitch_and_unstitch", "unstitch_alone"])
+def test_stitch_roofline_counts_each_program_with_its_own_time(
+        fixture, drop, nbytes, ns):
+    from bench.metrics import reader
+
+    run = _traced_run(fixture, drop)
+    assert reader("stitch_roofline")(run) == pytest.approx(
+        100.0 * nbytes / 819e9 / (ns * 1e-9))
+
+
+def test_stitch_roofline_reads_nothing_without_either_program(fixture):
+    from bench.metrics import reader
+
+    run = _traced_run(fixture, ("jit_stitch_canvases",
+                                "jit_unstitch_patches"))
+    assert reader("stitch_roofline")(run) is None
