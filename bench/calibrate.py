@@ -6,8 +6,9 @@ controls put in the program's place on the same invocations:
 
 * ``int8`` — the program's own int8-weight path (``quant_weights``,
   ``models/quantize.py``) on the benchmark's weights;
-* ``fp8`` — the reference with every matrix product's operands rounded
-  to float8 (e4m3).
+* ``fp8`` — the configuration's trunk reference (its ``bench/trunks/``
+  module's ``forward``) with every matrix product's operands rounded to
+  float8 (e4m3).
 
     python3 bench/calibrate.py --workload tangram.crowd.r80 \\
         --seeds 11,12,13 --seconds 12
@@ -29,7 +30,8 @@ sys.path.insert(0, str(ROOT))
 
 
 def controls(h):
-    """{name: canvases -> (obj, boxes)} for the harness's current weights."""
+    """{name: (canvases, records) -> (obj, boxes)} for the harness's
+    current weights."""
     import jax
     import jax.numpy as jnp
 
@@ -41,17 +43,15 @@ def controls(h):
     params_q = quantize_lib.quantize_params(
         detector_lib.param_specs(cfg_q), h.params)
     serve_q = jax.jit(lambda p, x: detector_lib.serve(cfg_q, p, x, h.rules))
-    fp8 = jax.jit(lambda p, x: reference.forward(
-        p, x, h.arch, dtype=jnp.float8_e4m3fn))
 
-    def int8(canvases):
+    def int8(canvases, _records):
         outs = [serve_q(params_q, jnp.asarray(c[None])) for c in canvases]
         return (np.concatenate([np.asarray(o) for o, _ in outs]),
                 np.concatenate([np.asarray(b) for _, b in outs]))
 
-    def lowp(canvases):
-        raw = np.concatenate([np.asarray(fp8(h.params, jnp.asarray(c[None])))
-                              for c in canvases])
+    def lowp(canvases, records):
+        raw = h.trunk.forward(h.params, canvases, records, h.arch,
+                              dtype=jnp.float8_e4m3fn)
         return reference.decode(raw, h.m)
 
     return {"int8": int8, "fp8": lowp}

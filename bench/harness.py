@@ -27,7 +27,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
-from bench import reference, traffic
+from bench import reference, traffic, trunks
 from bench.model import (build_serve_fn, check_registry, fused_kwargs,
                          latency_table, make_weights, serve_defaults)
 
@@ -50,11 +50,12 @@ EXACT = ("placement_faults", "evidence_mismatch", "route_mismatch",
 
 def load_config(name: str) -> dict:
     cfg = json.loads((CONFIG_DIR / f"{name}.json").read_text())
-    for key in ("name", "model", "source", "arch", "max_canvases",
+    for key in ("name", "model", "trunk", "source", "arch", "max_canvases",
                 "executor", "routed_share",
                 "latency_profile", "limits"):
         if key not in cfg:
             raise ValueError(f"configuration {name!r} lacks {key!r}")
+    trunks.load(cfg["trunk"])
     return cfg
 
 
@@ -74,6 +75,7 @@ class InvRecord:
     resolve_s: float = 0.0
     sync_s: float = 0.0
     traced: bool = False            # submitted while the profiler ran
+    records: Optional[np.ndarray] = None  # the plan's (B, K, 6) placements
 
 
 @dataclasses.dataclass
@@ -81,6 +83,7 @@ class Run:
     """What one window left behind, for the metric readers."""
     seconds: float
     arch: dict
+    trunk: object                   # the configuration's bench.trunks module
     peak: dict
     setup_s: float
     t_gen: np.ndarray               # per offered patch
@@ -112,6 +115,7 @@ class Harness:
                                              flush=True))
         self.config, self.mix = config, mix
         self.arch = dict(config["arch"])
+        self.trunk = trunks.load(config["trunk"])
         self.m = self.arch["canvas"]
         devices = jax.devices()
         self.devices = devices[:chips]
@@ -347,7 +351,8 @@ class Harness:
         compiles.stop()
 
         run = Run(
-            seconds=seconds, arch=self.arch, peak=self.peak,
+            seconds=seconds, arch=self.arch, trunk=self.trunk,
+            peak=self.peak,
             setup_s=setup_s,
             t_gen=np.array([a.patch.t_gen for a in arrivals]),
             deadline=np.array([a.patch.deadline for a in arrivals]),
@@ -368,14 +373,12 @@ class Harness:
     # ------------------------------------------------------------- check ----
 
     def check(self, kept: dict, run: Run, control=None) -> Dict[str, float]:
-        """Compare the kept invocations with the plain reference.
+        """Compare the kept invocations with the plain reference (the
+        configuration's trunk module's ``forward``).
 
-        ``control`` (a callable canvases -> (obj, boxes)) puts another
-        computation in the program's place: the check is then of it."""
-        import jax
-        import jax.numpy as jnp
-
-        ref_fwd = jax.jit(lambda p, x: reference.forward(p, x, self.arch))
+        ``control`` (a callable (canvases, records) -> (obj, boxes)) puts
+        another computation in the program's place: the check is then of
+        it."""
         obj_gap = box_gap = 0.0
         obj_sq = box_sq = 0.0
         faults = evidence = routes = missing = 0
@@ -391,12 +394,11 @@ class Harness:
                       for p in patches]
             faults += reference.placement_faults(plan.records, patches, self.m)
             canvases = reference.stitch(frames, patches, plan.records, self.m)
-            raw = np.concatenate([
-                np.asarray(ref_fwd(self.params, jnp.asarray(c[None])))
-                for c in canvases])
+            raw = self.trunk.forward(self.params, canvases, plan.records,
+                                     self.arch)
             obj_ref, box_ref = reference.decode(raw, self.m)
             if control is not None:
-                obj_p, box_p = control(canvases)
+                obj_p, box_p = control(canvases, plan.records)
             else:
                 obj_p, box_p = program_outputs(k, self.m)
             obj_p = np.asarray(obj_p, np.float64)
@@ -609,7 +611,7 @@ class _WindowState:
                 canvas_area=sum(c.m * c.n for c in inv.canvases),
                 t_slack=inv.t_slack,
                 live_pixels=sum(p.w * p.h for p in inv.patches),
-                traced=spans.tracing)
+                traced=spans.tracing, records=plan.records)
             self.records.append(rec)
             inv._bench = rec
             if (rec.ordinal < len(self.planned)

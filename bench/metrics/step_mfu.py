@@ -1,9 +1,8 @@
-"""Whole step: trunk operations of every canvas run, over the billed
-seconds of those invocations (submit to delivered completion) times the
-bf16 peak.  It stays a bound when a later change takes a kernel off the
-path.  Read over the invocations outside the trace, since
+"""Whole step: the trunk module's operations of every invocation, over the
+billed seconds of those invocations (submit to delivered completion)
+times the bf16 peak.  It stays a bound when a later change takes a kernel
+off the path.  Read over the invocations outside the trace, since
 tracing slows the host path that most of those seconds are."""
-from bench import work
 from bench.metrics._invocations import untraced
 
 
@@ -12,5 +11,5 @@ def read(run):
     span = sum(r.t_done - r.t_start for r in inv)
     if not inv or span <= 0:
         return None
-    flops = sum(r.canvases for r in inv) * work.trunk_flops(run.arch)
+    flops = sum(run.trunk.flops(run.arch, r) for r in inv)
     return 100.0 * flops / (span * run.peak["bf16_flops_per_s"])

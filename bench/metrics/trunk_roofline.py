@@ -1,6 +1,6 @@
 """Trunk program: least time of its work on the chip (the larger of the
-benchmark's count of operations over the bf16 peak and of bytes over the
-HBM peak; at these sizes operations bound it) over the device time of
+trunk module's count of operations over the bf16 peak and of bytes over
+the HBM peak; at these sizes operations bound it) over the device time of
 the trunk's XLA modules, for the invocations in the trace."""
 from bench import work
 from bench.metrics._invocations import traced
@@ -12,6 +12,6 @@ def read(run):
     if not inv or t_dev <= 0:
         return None
     least = sum(work.roofline_seconds(
-        r.canvases * work.trunk_flops(run.arch),
-        work.trunk_bytes(run.arch, r.canvases), run.peak)[0] for r in inv)
+        run.trunk.flops(run.arch, r), run.trunk.bytes(run.arch, r),
+        run.peak)[0] for r in inv)
     return 100.0 * least / t_dev

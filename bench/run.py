@@ -9,7 +9,8 @@ from the root of a checkout.  The cell names a configuration
 read by ``bench/metrics/<metric>.py``.  The run sets up (timed as
 ``setup_s``), drives ``--seconds`` of open-loop arrivals at their wall
 times, drains, compares a sample of the window's invocations with the
-plain reference (``bench/reference.py``), and prints one JSON line as the
+plain reference (``bench/reference.py`` around the configuration's trunk
+module, ``bench/trunks/<trunk>.py``), and prints one JSON line as the
 last line of standard output: ``correct``, ``attempted``, ``failed``,
 ``metrics`` (the cell's end-to-end metrics, or with ``--trace 1`` its
 per-layer metrics), ``device``, with ``--trace 1`` ``breakdown``, and last

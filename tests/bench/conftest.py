@@ -1,12 +1,15 @@
 """Shared pieces of the benchmark's own tests: CPU-sized cells.
 
 The benchmark runs on a TPU; these tests drive its harness on the CPU at
-a tiny size (2-layer trunks on 128-pixel canvases, 480x270 frames), one
-cell per configuration of the benchmark, so that everything but the chip
-is exercised in every test run.
+a tiny size (each configuration's ``tiny_arch``: 2-layer trunks on
+128-pixel canvases, 480x270 frames), one cell per configuration of
+``BENCHMARK.json``, so that everything but the chip is exercised in every
+test run, and a configuration added as files is exercised with them.
 """
 from __future__ import annotations
 
+import json
+import re
 import sys
 from pathlib import Path
 
@@ -24,31 +27,28 @@ if str(ROOT) not in sys.path:
 #: and 0.081-0.124 and 0.169-0.218
 TINY_LIMITS = {"obj_gap": 0.015, "box_gap": 0.03}
 
-#: each benchmark configuration's trunk at a CPU size, keeping what sets
-#: its cells apart: ViT-S/16 has four times ViT-B/32's tokens a canvas
-#: at half its width
-TINY_ARCH = {
-    "tangram": dict(canvas=128, patch=16, n_layers=2, d_model=64,
-                    n_heads=4, d_ff=128),
-    "vit_s16": dict(canvas=128, patch=8, n_layers=2, d_model=32,
-                    n_heads=2, d_ff=64),
-}
 
-#: one tiny cell per configuration, on the traffic of its cell
-TINY_CELLS = [
-    {"name": "tangram.crowd.r80", "config": "tangram", "traffic": "crowd4k",
-     "chips": 1},
-    {"name": "vit_s16.sparse.r80", "config": "vit_s16",
-     "traffic": "sparse4k", "chips": 1},
-]
+def tiny_cells(bench: dict) -> list:
+    """One tiny cell per configuration, on the mix of its first cell with
+    the rate taken off (``crowd4k.fps2.4`` -> ``crowd4k``): the tiny
+    harness sets its own rate."""
+    cells = []
+    for c in bench["configs"]:
+        w = next(w for w in bench["workloads"] if w["config"] == c["name"])
+        cells.append({"name": w["name"], "config": c["name"],
+                      "traffic": re.sub(r"\.fps[0-9.]+$", "", w["traffic"]),
+                      "chips": 1})
+    return cells
+
+
+TINY_CELLS = tiny_cells(json.loads((ROOT / "BENCHMARK.json").read_text()))
 
 
 def tiny_config(name: str) -> dict:
     from bench import harness
 
     cfg = harness.load_config(name)
-    cfg["arch"] = dict(TINY_ARCH[name], param_dtype="bfloat16",
-                       compute_dtype="bfloat16")
+    cfg["arch"] = dict(cfg["arch"], **cfg["tiny_arch"])
     cfg["latency_profile"] = {"batch_sizes": [1, 2, 4], "iters": 2,
                               "warmup": 1}
     cfg["limits"] = dict(TINY_LIMITS)

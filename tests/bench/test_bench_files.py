@@ -1,6 +1,6 @@
 """BENCHMARK.json against the benchmark's contract, every file it names,
 the refusal to run anywhere but on a TPU, and that a cell is added by
-files and an entry alone."""
+files and an entry alone, a new trunk family with it."""
 import json
 import os
 import re
@@ -11,7 +11,7 @@ from pathlib import Path
 
 import pytest
 
-from tests.bench.conftest import TINY_CELLS, tiny_config, tiny_mix
+from tests.bench.conftest import TINY_CELLS
 
 ROOT = Path(__file__).resolve().parents[2]
 BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
@@ -56,6 +56,7 @@ def test_configurations():
         assert all(NAME.match(k) for k in c["reduced"])
         cfg = harness.load_config(c["name"])
         assert cfg["name"] == c["name"] and cfg["source"] == c["source"]
+        assert set(cfg["tiny_arch"]) <= set(cfg["arch"])
 
 
 def test_workloads():
@@ -127,31 +128,60 @@ def read(run):
     return len(run.t_gen) / run.seconds
 '''
 
+#: a trunk family of its own name that computes as the ViT on the pixels
+#: of the tokens that the invocation's placements cover (all that the
+#: stitch put on the canvases, so only placements that reach it leave the
+#: outputs unchanged); ``{plant}`` puts a fault into its forward
+NEW_TRUNK = '''"""A trunk family that computes as the ViT on its live tokens."""
+import numpy as np
+
+from bench.trunks import vit
+
+flops, params, bytes = vit.flops, vit.params, vit.bytes
+
+
+def forward(params, canvases, records, arch, dtype="float32"):
+    assert len(records) == len(canvases)
+    p = arch["patch"]
+    live = vit.live_mask(records, arch).repeat(p, 1).repeat(p, 2)
+    raw = vit.forward(params, canvases * live[..., None], records, arch,
+                      dtype)
+    return raw{plant}
+'''
+#: the planted fault: the objectness logit of every cell raised by 1
+PLANTED = " + np.array([1.0, 0, 0, 0, 0], np.float32)"
+
 RUNNER = '''
 import json, sys
 sys.path[:0] = [".", {src!r}]
-from bench import harness, run, traffic
+from bench import run
+from tests.bench.conftest import tiny_config, tiny_mix
 bench = run.load_benchmark()
 cell = run.cell_of(bench, "tiny.new")
-out = run.result_line(bench, cell, harness.load_config(cell["config"]),
-                      traffic.load_mix(cell["traffic"]), 2**31 + 9, 2.0, 0,
+out = run.result_line(bench, cell, tiny_config(cell["config"]),
+                      tiny_mix(cell["traffic"]), 2**31 + 9, 2.0, 0,
                       require_tpu=False, log=lambda m: None)
 print(json.dumps(out))
 '''
 
 
-@pytest.mark.parametrize("base", TINY_CELLS, ids=[c["config"]
-                                                  for c in TINY_CELLS])
-def test_a_cell_is_added_by_files_and_an_entry_alone(tmp_path, base):
-    """A new configuration, traffic mix and metric, each a file of its
-    own, and one new entry in BENCHMARK.json: the unchanged harness finds
-    them by name and runs the new cell (here on the CPU, at a tiny size,
-    from each configuration's file and its cell's traffic)."""
-    shutil.copytree(ROOT / "bench", tmp_path / "bench",
-                    ignore=shutil.ignore_patterns("__pycache__"))
-    cfg = dict(tiny_config(base["config"]), name="tiny")
+def run_new_cell(tmp_path, base, plant=""):
+    """Copy the benchmark and its tests, add a configuration ``tiny`` on a
+    new trunk module ``toy`` (with ``plant`` in its forward), a traffic
+    mix, a metric and a cell, each a file or an entry of its own, and run
+    the cell through the unchanged harness and ``conftest.py`` (here on
+    the CPU, at the configuration's tiny size)."""
+    for d in ("bench", "tests/bench"):
+        shutil.copytree(ROOT / d, tmp_path / d,
+                        ignore=shutil.ignore_patterns("__pycache__"))
+    (tmp_path / "bench/trunks/toy.py").write_text(
+        NEW_TRUNK.format(plant=plant))
+    cfg = json.loads((ROOT / f"bench/configs/{base['config']}.json")
+                     .read_text())
+    cfg.update(name="tiny", trunk="toy")
     (tmp_path / "bench/configs/tiny.json").write_text(json.dumps(cfg))
-    mix = dict(tiny_mix(base["traffic"]), mix="tiny")
+    mix = dict(json.loads((ROOT / f"bench/traffic/{base['traffic']}.json")
+                          .read_text()), mix="tiny")
     (tmp_path / "bench/traffic/tiny4k.json").write_text(json.dumps(mix))
     (tmp_path / "bench/metrics/patches_offered.py").write_text(NEW_METRIC)
     bench = json.loads((ROOT / "BENCHMARK.json").read_text())
@@ -171,7 +201,29 @@ def test_a_cell_is_added_by_files_and_an_entry_alone(tmp_path, base):
         [sys.executable, "-c", RUNNER.format(src=str(ROOT / "src"))],
         cwd=tmp_path, env=env, capture_output=True, text=True, timeout=280)
     assert p.returncode == 0, p.stderr[-3000:]
-    out = json.loads(p.stdout.strip().splitlines()[-1])
+    return json.loads(p.stdout.strip().splitlines()[-1])
+
+
+@pytest.mark.parametrize("base", TINY_CELLS, ids=[c["config"]
+                                                  for c in TINY_CELLS])
+def test_a_cell_is_added_by_files_and_an_entry_alone(tmp_path, base):
+    """A new configuration on a new trunk module, a traffic mix and a
+    metric, each a file of its own, and one new entry in BENCHMARK.json:
+    the unchanged harness finds them by name and runs the new cell, and
+    its check is correct."""
+    out = run_new_cell(tmp_path, base)
     assert out["correct"], out["checks"]
     assert out["metrics"]["patches_offered"]["value"] > 0
     assert {"setup_s", "billed_s_per_kpatch"} <= set(out["metrics"])
+
+
+@pytest.mark.parametrize("base", TINY_CELLS, ids=[c["config"]
+                                                  for c in TINY_CELLS])
+def test_a_fault_in_the_named_trunk_module_is_not_correct(tmp_path, base):
+    """The check compares with the trunk module the configuration names:
+    a fault planted in that module's forward makes the run not correct,
+    on the objectness it moved."""
+    out = run_new_cell(tmp_path, base, plant=PLANTED)
+    assert not out["correct"]
+    checks = out["checks"]
+    assert checks["obj_gap"]["value"] > checks["obj_gap"]["limit"], checks
