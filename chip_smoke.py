@@ -26,8 +26,10 @@ One-chip phases:
    canvases.  The weights are random (seeded by the model name), and the
    untrained trunk's objectness sits near 0.3 on these frames, so the
    runs route at ``THRESHOLD`` instead of the default 0.5, which would
-   route nothing and leave nothing to compare.  The Pallas-stitch run
-   must route exactly the XLA run's detections.  The fused run computes
+   route nothing and leave nothing to compare.  ``--use-pallas-stitch``
+   no longer changes the served path (the unfused path stitches and cuts
+   the evidence on the host), so that run must route exactly the XLA
+   run's detections.  The fused run computes
    the patch embedding in another summation order, so its bf16 tokens
    differ by an ulp here and there: each of its detections and each of
    the XLA run's must have a partner in the other run within

@@ -2,10 +2,10 @@
 
 This is the paper's deployment loop with actual tensors: the edge pipeline
 emits patches, the unified serving engine (``core.engine``) batches them
-through the SLO-aware invoker pool, the Pallas stitch kernel (interpret
-mode on CPU) assembles canvases, and a jit-compiled ViT detector serves
-each batch on the ``DeviceExecutor`` — the exact control plane the
-simulator benchmarks run on.
+through the SLO-aware invoker pool, the executor stitches canvases on the
+host, and a jit-compiled ViT detector serves each batch on the
+``DeviceExecutor`` — the exact control plane the simulator benchmarks run
+on.
 
     PYTHONPATH=src python examples/serve_e2e.py
 """

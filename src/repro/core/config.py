@@ -67,8 +67,8 @@ class ServeConfig:
 
     # --- execution ------------------------------------------------------
     executor: str = "sim"            # sim | device | async_device
-    use_pallas: bool = False         # Pallas unstitch kernel on the unfused
-                                     # device path (it stitches on the host)
+    use_pallas: bool = False         # no effect: the unfused device path
+                                     # stitches and unstitches on the host
     fuse: bool = False               # fused stitch->embed / decode->gather
                                      # device hot path (fused_embed.py)
     quantize: bool = False           # serve int8-resident weights: models

@@ -16,7 +16,7 @@ independent of how fast the host's XLA happens to be:
   ``AsyncDeviceExecutor`` drives stub and real device identically.
 * ``sync(tree)`` is the executor's ``sync`` hook: joins every
   ``DeviceFuture`` in the tree and ``block_until_ready``s any real JAX
-  arrays alongside them (the stitch/unstitch legs still run under XLA).
+  arrays alongside them.
 """
 from __future__ import annotations
 

@@ -49,7 +49,7 @@ Scheduling policy and execution substrate are independent axes:
   ``core.baselines`` are alternative batchers over the same loop.
 * an **executor** runs a fired invocation: :class:`SimExecutor` submits to
   the serverless ``Platform`` model, :class:`DeviceExecutor` runs the real
-  stitch -> (sharded) detect -> unstitch -> route pipeline synchronously,
+  stitch -> (sharded) detect -> route pipeline synchronously,
   and :class:`AsyncDeviceExecutor` exploits JAX async dispatch — submit
   returns after the host-side stitch + jit dispatch, the device crunches
   in the background while the engine keeps ingesting arrivals and
@@ -491,7 +491,7 @@ class ModelRuntime:
 
 class DeviceExecutor:
     """Executor over the real pipeline: stitch -> (data-parallel)
-    detect -> inverse unstitch -> per-frame routing, joined synchronously
+    detect -> per-frame routing and evidence, joined synchronously
     at submit (``t_finish`` = ``t_submit`` + measured wall execution, the
     same quantity the offline profiling table estimates, so SLO
     accounting stays consistent between simulation and device).
@@ -517,18 +517,21 @@ class DeviceExecutor:
     unfused path stitches the crops onto the (B, M, N, 3) float32 canvas
     batch on the host (``stitch_ops.stitch_plan_host``, into a buffer
     reused once the invocation that last used it is joined) and sends
-    that batch, padded to the mesh's data axis; the trunk and the
-    unstitch read it on the device.  The fused path (``fuse`` on a runtime with
-    the fused fields) sends the pow2-padded patch slots
-    (``stitch_ops.pack_plan_host``), which its stitch->embed kernel
-    assembles into canvases in VMEM.  Both send the plan's records.
+    that batch, padded to the mesh's data axis; the trunk reads it on
+    the device, and only its ``obj`` and ``boxes`` come back.  Each
+    patch's evidence pixels are cut from the host batch
+    (``stitch_ops.unstitch_plan_host``) before the buffer is reused.  The
+    fused path (``fuse`` on a runtime with the fused fields) sends the
+    pow2-padded patch slots (``stitch_ops.pack_plan_host``), which its
+    stitch->embed kernel assembles into canvases in VMEM, and serves the
+    evidence from those slots.  Both send the plan's records.
 
     ``obj_threshold`` is the objectness a grid cell needs to be routed
-    as a detection.  ``use_pallas`` runs the unfused path's unstitch as
-    a Pallas kernel (its stitch runs on the host), and ``fuse`` the
-    fused stitch->embed and decode->gather kernels;
-    ``stitch_ops.pallas_impl`` compiles them on TPU and interprets them
-    on CPU.
+    as a detection.  ``fuse`` runs the fused stitch->embed and
+    decode->gather kernels; ``stitch_ops.pallas_impl`` compiles them on
+    TPU and interprets them on CPU.  ``use_pallas`` is accepted and no
+    longer changes the served path: the unfused path runs no stitch
+    kernel on the device.
 
     ``telemetry`` (a :class:`~repro.core.telemetry.Telemetry`, off by
     default) records the launch and finalize spans and their parts.  The
@@ -562,7 +565,6 @@ class DeviceExecutor:
         self.serve_fn = serve_fn
         self.params = params
         self.m, self.n = canvas_m, canvas_n
-        self.use_pallas = use_pallas
         self.fuse = fuse
         self.mesh = mesh
         self.rules = rules
@@ -747,21 +749,12 @@ class DeviceExecutor:
             self.n_invocations += 1
             self.n_fused += 1
             return {"fused": out}
+        # the evidence (each patch's pixels) is cut from the host canvas
+        # batch at finalize, so only the detector's outputs come back
         obj, boxes = rt.serve_fn(rt.params, pixels)
-        # inverse gather, grouped by source frame alongside the routed
-        # detections.  The box head has no pixel-space output, so the
-        # canvases stand in for a per-pixel head (e.g. segmentation): the
-        # gathered slots equal the input crops, and the value here is
-        # exercising the unstitch path every invocation.  slot_capacity
-        # (pow2-bucketed) keeps the jit static shapes stable across
-        # invocations; rows past num_patches are never read.
-        impl = stitch_ops.pallas_impl() if self.use_pallas else "xla"
-        patch_out = stitch_ops.unstitch_patches(
-            pixels, records, plan.slot_capacity, plan.hmax, plan.wmax,
-            impl=impl)
         self.n_invocations += 1
         self.n_host_stitched += 1
-        return {"obj": obj, "boxes": boxes, "patch_out": patch_out}
+        return {"obj": obj, "boxes": boxes}
 
     def _finalize(self, inv: Invocation, payload: dict) -> Completion:
         """Join the device values and do the host-side routing."""
@@ -777,43 +770,42 @@ class DeviceExecutor:
                       inv=payload["inv"]) as finalize:
             with tel.span("tangram.executor.sync"):
                 sync(payload["fused"] if fused
-                     else (payload["obj"], payload["patch_out"]))
+                     else (payload["obj"], payload["boxes"]))
             with tel.span("tangram.executor.fetch"):
                 if fused:
                     host = [np.asarray(payload["fused"])]
                 else:
-                    host = [np.asarray(payload[k])
-                            for k in ("obj", "boxes", "patch_out")]
-            if not fused:
-                # the outputs are on the host, so nothing on the device
-                # reads the host canvas batch any more
-                buf = payload["canvases"]
-                self._free_canvases.setdefault(buf.shape, []).append(buf)
+                    host = [np.asarray(payload[k]) for k in ("obj", "boxes")]
             got = sum(a.nbytes for a in host)
             self.bytes_from_device += got
             finalize.set(bytes_from_device=got)
             with tel.span("tangram.executor.route"):
+                # the evidence is each patch's pixels, grouped by frame
+                # alongside its detections: the box head has no
+                # pixel-space output, so the input crops stand in for a
+                # per-pixel head (e.g. segmentation).  Each path serves
+                # them from the pixel buffer it holds on the host.
                 if fused:
                     per_frame = stitch_ops.route_fused(
                         plan, inv.patches, host[0],
                         obj_threshold=self.obj_threshold)
-                    # the unfused evidence (gathered slots) equals the
-                    # input crops by construction, so the fused path
-                    # serves it from the packed slots it already holds
-                    # on the host
-                    evidence = payload["slots"]
+                    slots = payload["slots"]
+                    # copy: a view would pin the whole pow2-padded slots
+                    evidence = [np.ascontiguousarray(slots[i, :p.h, :p.w])
+                                for i, p in enumerate(inv.patches)]
                 else:
                     per_frame = stitch_ops.route_detections(
                         plan, inv.patches, host[0], host[1],
                         obj_threshold=self.obj_threshold)
-                    evidence = host[2]
+                    buf = payload["canvases"]
+                    evidence = stitch_ops.unstitch_plan_host(
+                        buf, plan, inv.patches)
+                    # the evidence is copied out and the device has
+                    # joined, so nothing reads the canvas batch any more
+                    self._free_canvases.setdefault(buf.shape, []).append(buf)
                 per_frame_pixels: Dict[object, List[np.ndarray]] = {}
-                for i, patch in enumerate(inv.patches):
-                    # copy: a view would pin the whole pow2-padded batch
-                    # in memory
-                    per_frame_pixels.setdefault(patch.frame_id, []).append(
-                        np.ascontiguousarray(
-                            evidence[i, :patch.h, :patch.w]))
+                for patch, px in zip(inv.patches, evidence):
+                    per_frame_pixels.setdefault(patch.frame_id, []).append(px)
         wall = self.clock() - payload["t0"]
 
         self.n_detections += sum(len(v) for v in per_frame.values())
@@ -868,8 +860,7 @@ class AsyncDeviceExecutor(DeviceExecutor):
         p = handle.payload
         if "fused" in p:
             return _leaf_ready(p["fused"])
-        return (_leaf_ready(p["obj"]) and _leaf_ready(p["patch_out"])
-                and _leaf_ready(p["boxes"]))
+        return _leaf_ready(p["obj"]) and _leaf_ready(p["boxes"])
 
 
 def shard_canvases(canvases, mesh, rules):

@@ -3,7 +3,10 @@
 The device side is batched end-to-end: ``stitch_canvases`` assembles a
 whole multi-canvas batch in one call, ``unstitch_patches`` gathers every
 placement back out, and ``route_detections`` maps canvas-space detector
-outputs to per-frame boxes via the same :class:`BatchPlan` records.
+outputs to per-frame boxes via the same :class:`BatchPlan` records.  The
+unfused serve path stitches and unstitches on the host instead
+(``stitch_plan_host``, ``unstitch_plan_host``); the device versions are
+their references.
 """
 from __future__ import annotations
 
@@ -141,6 +144,29 @@ def stitch_plan_host(frame_pixels: Sequence[np.ndarray], plan: BatchPlan,
             # the slot's zero padding past a short crop (or an empty slot)
             out[bi, y:y + h, x:x + w] = 0.0
         out[bi, y:y + ph, x:x + pw] = px[:ph, :pw]
+    return out
+
+
+def unstitch_plan_host(canvases: np.ndarray, plan: BatchPlan,
+                       patches: Sequence[Patch]) -> List[np.ndarray]:
+    """Host unstitch: each patch's pixels, cut back out of the canvases.
+
+    The mirror of :func:`stitch_plan_host`.  Returns, for queue patch i,
+    a new contiguous (patch.h, patch.w, C) array: bit for bit
+    ``unstitch_patches(canvases, records, ...)[i, :patch.h, :patch.w]``,
+    so the valid record's (h, w) region at (y, x) of its canvas (placed
+    inside the canvas by the packer), zero past it, and zero for a patch
+    no valid record places.  A copy, not a view: a view would pin the
+    whole batch, and its buffer is stitched into again.
+    """
+    c = canvases.shape[-1]
+    out = [np.zeros((min(p.h, plan.hmax), min(p.w, plan.wmax), c),
+                    canvases.dtype) for p in patches]
+    for bi, slot, x, y, w, h in plan.placements():
+        px = out[slot]
+        h, w = min(h, px.shape[0]), min(w, px.shape[1])
+        assert y + h <= plan.canvas_m and x + w <= plan.canvas_n, (x, y, w, h)
+        px[:h, :w] = canvases[bi, y:y + h, x:x + w]
     return out
 
 

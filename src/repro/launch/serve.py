@@ -4,8 +4,8 @@ Edge side per frame: GMM background subtraction -> RoI extraction ->
 adaptive frame partitioning (Alg. 1).  Cloud side: the unified serving
 engine (``core.engine``) drives the per-SLO-class invoker pool over
 bandwidth-shaped arrivals and executes every fired invocation on the
-device pipeline — batched stitch -> (data-parallel) detect -> inverse
-unstitch -> per-frame routing.  Timers fire at their scheduled times
+device pipeline — batched stitch -> (data-parallel) detect -> per-frame
+routing.  Timers fire at their scheduled times
 (not at the next arrival), and the executor's frame store is refcounted:
 a frame is evicted the moment every patch cut from it has been routed.
 
@@ -220,9 +220,9 @@ def main(argv=None):
                         "ingestion window: drop frames, degrade RoI "
                         "quality (drops at 2x), or ignore")
     p.add_argument("--use-pallas-stitch", action="store_true",
-                   help="run the unfused path's unstitch as a Pallas "
-                        "kernel (compiled on TPU, interpreted on CPU); "
-                        "its canvases are stitched on the host")
+                   help="no effect, kept for compatibility: the unfused "
+                        "path stitches its canvases and cuts the evidence "
+                        "back out on the host, with no stitch kernel")
     p.add_argument("--obj-threshold", type=float, default=0.5,
                    help="objectness a detector cell needs to be routed "
                         "back to its frame as a detection")

@@ -1,7 +1,8 @@
-"""The unfused path's host stitch (``stitch_ops.stitch_plan_host``) against
-the device stitch it replaces: the canvas batch is the same bit for bit,
-and an unfused ``DeviceExecutor`` serves what the slots -> device-stitch
-pipeline served."""
+"""The unfused path's host stitch and unstitch (``stitch_ops.stitch_plan_host``,
+``stitch_ops.unstitch_plan_host``) against the device kernels they replace:
+the canvas batch and each patch's evidence are the same bit for bit, and an
+unfused ``DeviceExecutor`` serves what the slots -> device-stitch ->
+device-unstitch pipeline served."""
 import dataclasses
 
 import jax.numpy as jnp
@@ -56,15 +57,44 @@ def random_case(seed):
     crops[gone] = np.zeros((patches[gone].h, patches[gone].w, 3), np.float32)
     short = int(rng.integers(0, n))
     crops[short] = crops[short][: max(1, patches[short].h // 2)]
-    return crops, plan
+    return crops, plan, patches
 
 
 @pytest.mark.parametrize("seed", range(8))
 def test_host_stitch_equals_device_stitch_bitwise(seed):
-    crops, plan = random_case(seed)
+    crops, plan, _ = random_case(seed)
     assert plan.num_canvases > 1
     assert_bitwise(stitch_ops.stitch_plan_host(crops, plan),
                    device_stitch(crops, plan))
+
+
+def device_unstitch(canvases, plan, patches):
+    """The device path it replaces: the XLA reference gather into
+    pow2-padded slots, each patch's (h, w) corner cut out."""
+    slots = np.asarray(stitch_ops.unstitch_patches(
+        jnp.asarray(canvases), jnp.asarray(plan.records), plan.slot_capacity,
+        plan.hmax, plan.wmax, impl="xla"))
+    return [slots[i, :p.h, :p.w] for i, p in enumerate(patches)]
+
+
+@pytest.mark.parametrize("seed", range(8))
+def test_host_unstitch_equals_device_unstitch_bitwise(seed):
+    """On noise canvases (so a cut one pixel off shows), with records
+    invalidated (those patches come back zero), a short crop and a
+    canvas-filling placement flush with every edge."""
+    _, plan, patches = random_case(seed)
+    rng = np.random.default_rng(100 + seed)
+    canvases = rng.normal(size=(plan.num_canvases, M, N, 3)).astype(
+        np.float32)
+    host = stitch_ops.unstitch_plan_host(canvases, plan, patches)
+    placed = {slot for _, slot, *_ in plan.placements()}
+    assert len(placed) < len(patches)               # some come back zero
+    assert len(host) == len(patches)
+    for i, (a, b) in enumerate(zip(host,
+                                   device_unstitch(canvases, plan, patches))):
+        assert a.flags.c_contiguous and a.base is None
+        assert_bitwise(a, b)
+        assert a.any() == (i in placed)
 
 
 def test_host_stitch_at_the_canvas_edges_into_a_used_buffer():
@@ -90,6 +120,13 @@ def test_host_stitch_at_the_canvas_edges_into_a_used_buffer():
     assert_bitwise(host[:2], device_stitch(crops, plan))
     assert not host[2:].any()
     np.testing.assert_array_equal(host[1, M - 32:, N - 32:], crops[3])
+    patches = [Patch(0, 0, w, h) for w, h in sizes] + [Patch(0, 0, 8, 8)]
+    back = stitch_ops.unstitch_plan_host(host, plan, patches)
+    for a, b in zip(back, device_unstitch(host[:2], plan, patches)):
+        assert_bitwise(a, b)
+    for i in (0, 1, 2, 3):                          # flush with the edges
+        assert_bitwise(back[i], crops[i])
+    assert not back[4].any() and not back[5].any()  # invalid; no crop
 
 
 def test_host_stitch_of_an_empty_plan():
@@ -120,9 +157,10 @@ def tiny():
 
 
 def oracle(tiny, inv, obj_threshold):
-    """The slots -> device-stitch pipeline, as the executor ran it before
-    the host stitch: the trunk's objectness, routed detections,
-    per-frame evidence and the canvas batch."""
+    """The slots -> device-stitch -> device-unstitch pipeline, as the
+    executor ran it before the host stitch and unstitch: the trunk's
+    objectness, routed detections, per-frame evidence and the canvas
+    batch."""
     plan = inv.batch_plan()
     crops = []
     for p in inv.patches:
@@ -143,11 +181,24 @@ def oracle(tiny, inv, obj_threshold):
     return np.asarray(obj), dets, pix, np.asarray(canvases)
 
 
+def assert_same_evidence(pix, want_pix):
+    assert set(pix) == set(want_pix)
+    for fid in want_pix:
+        assert len(pix[fid]) == len(want_pix[fid])
+        for a, b in zip(pix[fid], want_pix[fid]):
+            assert_bitwise(a, b)
+
+
 @pytest.mark.parametrize("how", ["xla", "pallas", "mesh"])
-def test_unfused_executor_serves_what_device_stitch_served(tiny, how):
+def test_unfused_executor_serves_what_device_stitch_served(tiny, how,
+                                                           monkeypatch):
     """Two invocations of three canvases each, the second stitched into
     the host batch the first left: each routes and serves what the
-    oracle does."""
+    oracle does, including the evidence of a patch past its frame, of
+    one whose frame was never added and of a placement flush with the
+    canvas's edges.  The first's evidence still holds after the second
+    reused its buffer, which was given back only once the evidence was
+    copied out; only ``obj`` and ``boxes`` come back from the device."""
     from repro.launch.mesh import make_serve_mesh
 
     full = tiny["patches"]
@@ -173,22 +224,49 @@ def test_unfused_executor_serves_what_device_stitch_served(tiny, how):
 
     ex = DeviceExecutor(serve_fn, tiny["params"], C, C,
                         obj_threshold=threshold, **kw)
+    unstitch = stitch_ops.unstitch_plan_host
+    free_at_unstitch = []
+
+    def watched(canvases, *a, **k):
+        free_at_unstitch.append(any(
+            b is canvases for b in ex._free_canvases.get(canvases.shape, [])))
+        return unstitch(canvases, *a, **k)
+    monkeypatch.setattr(stitch_ops, "unstitch_plan_host", watched)
     for fid in tiny["frames"]:
         ex.add_frame(fid, tiny["frames"][fid],
                      sum(1 for inv in invs for p in inv.patches
                          if p.frame_id == fid))
-    bufs = []
+    bufs, served = [], []
     for inv in invs:
-        _, want_dets, want_pix, want_canvases = oracle(tiny, inv, threshold)
+        obj, want_dets, want_pix, want_canvases = oracle(tiny, inv,
+                                                         threshold)
+        before = ex.bytes_from_device
         dets, pix = ex.resolve(ex.submit(inv)).outputs
         assert_bitwise(seen[-1], want_canvases)
         assert dets == want_dets
-        assert set(pix) == set(want_pix)
-        for fid in want_pix:
-            assert len(pix[fid]) == len(want_pix[fid])
-            for a, b in zip(pix[fid], want_pix[fid]):
-                assert_bitwise(a, b)
+        assert_same_evidence(pix, want_pix)
+        served.append((pix, want_pix))
+        # obj (B, s, s) and boxes (B, s, s, 4), float32 over the batch
+        # rows the device ran
+        assert ex.bytes_from_device - before == (
+            obj.nbytes * 5 * len(seen[-1]) // obj.shape[0])
         bufs.append(list(ex._free_canvases[(3, C, C, 3)]))
     assert len(bufs[0]) == 1 and bufs[1][0] is bufs[0][0]   # reused
+    assert free_at_unstitch == [False, False]
+    for pix, want_pix in served:                    # nothing overwritten
+        assert_same_evidence(pix, want_pix)
+    # the patch past its frame comes back zero past the frame's edge, the
+    # patch whose frame was never added all zero, and the canvas-filling
+    # patch as the frame's own pixels
+    first = served[0][0]
+    frame1 = tiny["frames"][1]
+    past = first[1][2]                              # rows 100:140, cols 220:280
+    assert past.shape == (40, 60, 3)
+    assert_bitwise(past[:C - 100, :2 * C - 220].copy(), frame1[100:, 220:])
+    rest = past.copy()
+    rest[:C - 100, :2 * C - 220] = 0.0
+    assert not rest.any()
+    assert first[2][0].shape == (60, 60, 3) and not first[2][0].any()
+    assert_bitwise(first[0][2], tiny["frames"][0][:C, :C])
     assert (ex.n_host_stitched, ex.n_fused) == (2, 0)
     assert ex.n_sharded == 0                        # one device

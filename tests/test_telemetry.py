@@ -130,8 +130,9 @@ def test_span_tree_of_one_invocation(tiny, cls, fuse):
 
 @pytest.mark.parametrize("fuse", [False, True], ids=["unfused", "fused"])
 def test_transfer_counters_are_the_arrays_nbytes(tiny, fuse):
-    """Bytes to the device are the slots' and records' own; bytes from it
-    are every device array the finalize fetches."""
+    """Bytes to the device are the pixel buffer's and records' own; bytes
+    from it are every device array the finalize fetches: the fused grids,
+    or the unfused path's ``obj`` and ``boxes`` alone."""
     kw = tiny["fused"] if fuse else {}
     ex = DeviceExecutor(tiny["serve_fn"], tiny["params"], M, N, **kw)
     patches = tiny["patches"]
@@ -141,7 +142,9 @@ def test_transfer_counters_are_the_arrays_nbytes(tiny, fuse):
                      "timer")
     payload = ex._launch(inv)
     device = ([payload["fused"]] if fuse else
-              [payload[k] for k in ("obj", "boxes", "patch_out")])
+              [payload[k] for k in ("obj", "boxes")])
+    assert set(payload) & {"fused", "obj", "boxes", "patch_out"} == (
+        {"fused"} if fuse else {"obj", "boxes"})
     want_from = sum(np.asarray(a).nbytes for a in device)
     ex._finalize(inv, payload)
     assert ex.bytes_to_device == plan_counts(inv, fused=fuse)[2]
